@@ -402,7 +402,6 @@ def measure_server_ingest() -> dict:
                                        server_update)
     from repro.core.stages import server_aggregate_sparse
     from repro.launch.hlo_analysis import analyze
-    from repro.launch.mesh import backend_spec
 
     cfg = COMPRESSION
     mc = MLPConfig(**cfg["mlp"])
@@ -423,7 +422,6 @@ def measure_server_ingest() -> dict:
                        + off).astype(np.int32))
     vals = jnp.asarray(rngn.standard_normal((n, nb * k)).astype(np.float32))
     x = jnp.zeros(d, jnp.float32)
-    spec = backend_spec()
     f32_stream = 4.0 * d
 
     # two-pass: two jits with the dense (d,) mean delta materialized
@@ -443,7 +441,6 @@ def measure_server_ingest() -> dict:
         "hlo_bytes": two_bytes,
         "hlo_streams": two_bytes / f32_stream,
         "hlo_rw_bytes": agg_hc.rw_bytes + upd_hc.rw_bytes,
-        "memory_s": two_bytes / spec.hbm_bw,
     }
 
     # fused: one jit per state dtype, dense delta never materialized
@@ -467,13 +464,11 @@ def measure_server_ingest() -> dict:
             "hlo_bytes": hc.bytes,
             "hlo_streams": hc.bytes / f32_stream,
             "hlo_rw_bytes": hc.rw_bytes,
-            "memory_s": hc.bytes / spec.hbm_bw,
             "bytes_reduction_vs_two_pass": two_bytes / hc.bytes,
         }
     return {
         "config": dict(d=d, n=n, block=bs, nb=nb, k=k,
-                       algorithm=fed.algorithm, option=fed.option,
-                       backend=spec.name),
+                       algorithm=fed.algorithm, option=fed.option),
         "uplink_bytes": float(vals.nbytes + idx.nbytes),
         "two_pass": two,
         "fused": fused,
@@ -867,7 +862,6 @@ _MESH_AB_CODE = '''
 import json, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.configs.base import ModelConfig, FedConfig, TrainConfig
 from repro.core.mesh import (build_fed_round, build_fed_rounds_scan,
                              fed_batch_defs, fed_state_defs, init_fed_state,
@@ -902,7 +896,7 @@ for agg in ("dense", "sparse"):
     ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
     bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                        is_leaf=pdefs.is_def)
-    steps[agg] = (jax.jit(compat.shard_map(
+    steps[agg] = (jax.jit(jax.shard_map(
         build_fed_rounds_scan(rnd), mesh=mesh,
         in_specs=(ssp, scan_batch_specs(bsp), P(None)),
         out_specs=(ssp, {{"loss": P(None), "wire_up_bytes": P(None)}})),
@@ -948,6 +942,7 @@ def measure_mesh_sparse_ab(rounds: int, reps: int = 3) -> dict:
     import sys
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"   # a host mesh: the chip stays ours
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     code = _MESH_AB_CODE.format(rounds=rounds, reps=reps)
@@ -1042,7 +1037,8 @@ def main():
     payload["server_ingest"] = si
     rows.append(csv_row(
         "rounds_server_ingest_fused_f32",
-        1e6 * si["fused"]["float32"]["memory_s"],
+        float("nan"),   # a byte count, not a time: no time is measured here
+        f"hlo_bytes={si['fused']['float32']['hlo_bytes']:.0f};"
         f"hlo_streams={si['fused']['float32']['hlo_streams']:.2f};"
         f"two_pass_streams={si['two_pass']['hlo_streams']:.2f};"
         "bytes_reduction="

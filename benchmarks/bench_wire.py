@@ -24,9 +24,8 @@ def _time(fn, *args, reps: int = 20):
     return (time.perf_counter() - t0) / reps * 1e6, out
 
 
-def bench_codec(name: str, d: int, ratio: float = 1 / 64,
-                pack_impl: str = "jnp"):
-    codec = make_wire_codec(name, ratio, pack_impl=pack_impl)
+def bench_codec(name: str, d: int, ratio: float = 1 / 64):
+    codec = make_wire_codec(name, ratio)
     x = jnp.asarray(np.random.default_rng(0).normal(size=d), jnp.float32)
     enc = jax.jit(codec.encode)
     dec = jax.jit(lambda b: codec.decode(b, d))
@@ -35,9 +34,8 @@ def bench_codec(name: str, d: int, ratio: float = 1 / 64,
     r = measured_vs_analytic(codec, d)
     exact = bool(jnp.all(out == codec.compressor.compress(x).reshape(-1)))
     mbps = d * 4 / (us_enc / 1e6) / 1e6
-    tag = f"{name}_pallas" if pack_impl == "pallas" else name
     return csv_row(
-        f"wire_{tag}_d{d}", us_enc + us_dec,
+        f"wire_{name}_d{d}", us_enc + us_dec,
         f"encode_us={us_enc:.0f};decode_us={us_dec:.0f};"
         f"encode_MBps={mbps:.0f};wire_bytes={r['measured_bytes']};"
         f"analytic_bits={r['analytic_bits']};"
@@ -48,7 +46,6 @@ def main():
     d = 100_000 if QUICK else 11_200_000
     rows = [bench_codec(name, d)
             for name in ("dense32", "topk", "blocktopk", "sign")]
-    rows.append(bench_codec("sign", d, pack_impl="pallas"))
 
     # end-to-end: a small FedCAMS run with wire=True through the simulated
     # network — cumulative measured bytes and simulated seconds per codec

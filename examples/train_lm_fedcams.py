@@ -27,6 +27,8 @@ ap.add_argument("--ratio", type=float, default=1 / 64)
 ap.add_argument("--checkpoint", default="")
 args = ap.parse_args()
 
+# a host mesh of forced CPU devices: never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault(
     "XLA_FLAGS",
     f"--xla_force_host_platform_device_count={args.clients * args.tp}")
@@ -38,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import FedConfig, ModelConfig, TrainConfig
 from repro.core import (build_fed_round, fed_batch_defs, fed_state_defs,
                         init_fed_state)
@@ -73,7 +74,7 @@ sdefs = fed_state_defs(model, fed)
 ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
 bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                    is_leaf=pdefs.is_def)
-step = jax.jit(compat.shard_map(build_fed_round(model, fed, train, ctx),
+step = jax.jit(jax.shard_map(build_fed_round(model, fed, train, ctx),
                                 mesh=mesh, in_specs=(ssp, bsp, P()),
                                 out_specs=(ssp, {"loss": P(),
                                                  "wire_up_bytes": P()})))

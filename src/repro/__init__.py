@@ -22,10 +22,8 @@ Wire formats & transport (repro.comm):
     ``FedConfig(wire=True)`` routes every FedSim round through
     encode→transport→decode (two-way compression exercises the downlink
     codec too) and surfaces measured ``wire_bytes`` / ``round_time_s`` into
-    ``FederatedTrainer.history``; ``kernels.bitpack`` provides the Pallas
-    1-bit pack/unpack the sign codec selects with ``pack_impl="pallas"``
-    (byte-identical to the default jnp path), and
-    ``benchmarks/bench_wire.py`` measures codec throughput and
+    ``FederatedTrainer.history``; ``kernels.bitpack`` holds the word-wise
+    sub-word packing the codecs use, and ``benchmarks/bench_wire.py`` measures codec throughput and
     measured-vs-analytic bytes.
 """
 

@@ -86,28 +86,21 @@ def _from_bytes(buf, dtype, count: int):
         buf[: count * width].reshape(count, width), dtype)
 
 
-def pack_uint(vals, nbits: int, impl: str = "jnp") -> jnp.ndarray:
+def pack_uint(vals, nbits: int) -> jnp.ndarray:
     """Pack unsigned ints (< 2**nbits) at ``nbits`` bits each, MSB-first,
     into a uint8 stream (zero-padded to a whole byte).
 
     Word-wise shift/or accumulation (kernels.bitpack): never materializes
     the ``(count, nbits)`` bit matrix the naive formulation needs — for
     blocktopk's 11-bit indices that intermediate is a 32× blowup over the
-    packed bytes. ``impl="pallas"`` routes through the Pallas kernel
-    (byte-identical; compiled on TPU, interpreted elsewhere)."""
-    from repro.kernels.bitpack import pack_uint as pack_uint_pl
+    packed bytes."""
     from repro.kernels.bitpack import pack_uint_words
-    if impl == "pallas":
-        return pack_uint_pl(vals, nbits)
     return pack_uint_words(vals, nbits)
 
 
-def unpack_uint(buf, nbits: int, count: int, impl: str = "jnp") -> jnp.ndarray:
+def unpack_uint(buf, nbits: int, count: int) -> jnp.ndarray:
     """Inverse of ``pack_uint``."""
-    from repro.kernels.bitpack import unpack_uint as unpack_uint_pl
     from repro.kernels.bitpack import unpack_uint_words
-    if impl == "pallas":
-        return unpack_uint_pl(buf, nbits, count)
     return unpack_uint_words(buf, nbits, count)
 
 
@@ -251,12 +244,7 @@ def make_topk_codec(ratio: float, value_dtype: str = "float32") -> WireCodec:
 
 
 def make_blocktopk_codec(ratio: float, block: int = 2048,
-                         value_dtype: str = "float32",
-                         pack_impl: str = "jnp") -> WireCodec:
-    """``pack_impl="pallas"`` packs/unpacks the sub-word index stream with
-    the kernels.bitpack Pallas kernels (byte-identical to the jnp path)."""
-    if pack_impl not in ("jnp", "pallas"):
-        raise ValueError(f"unknown pack_impl {pack_impl!r}")
+                         value_dtype: str = "float32") -> WireCodec:
     _, vdt, vb = _VALUE_DTYPES[value_dtype]
     int8 = value_dtype == "int8"
 
@@ -280,7 +268,7 @@ def make_blocktopk_codec(ratio: float, block: int = 2048,
         idx = gidx - (jnp.arange(nb, dtype=jnp.int32) * bs)[:, None]
         vals = sel.vals.reshape(nb, kb)
         parts = [_header("blocktopk", value_dtype, d, kb, bs),
-                 pack_uint(idx.astype(jnp.uint32), ib, pack_impl)]
+                 pack_uint(idx.astype(jnp.uint32), ib)]
         if int8:
             scale, q = _quantize(vals)
             parts += [_to_bytes(scale.astype(jnp.float32)),
@@ -289,24 +277,17 @@ def make_blocktopk_codec(ratio: float, block: int = 2048,
             parts.append(_to_bytes(vals.astype(vdt)))
         return jnp.concatenate(parts)
 
+    comp = make_blocktopk(ratio, block)
+
     def encode(x, rng=None):
         flat = x.reshape(-1).astype(jnp.float32)
-        d = flat.size
-        bs, nb, kb, ib = layout(d)
-        xb = jnp.pad(flat, (0, nb * bs - d)).reshape(nb, bs)
-        _, idx = lax.top_k(jnp.abs(xb), kb)              # (nb, kb)
-        vals = jnp.take_along_axis(xb, idx, axis=1)
-        gidx = idx.astype(jnp.int32) + (jnp.arange(nb, dtype=jnp.int32)
-                                        * bs)[:, None]
-        return encode_from_selection(
-            Selection(vals=vals.reshape(-1), idx=gidx.reshape(-1)), d)
+        return encode_from_selection(comp.select(flat), flat.size)
 
     def decode_to_selection(buf, d: int) -> Selection:
         bs, nb, kb, ib = layout(d)
         off = HEADER_BYTES
         nidx = (nb * kb * ib + 7) // 8
-        idx = unpack_uint(buf[off:off + nidx], ib, nb * kb,
-                          pack_impl).reshape(nb, kb)
+        idx = unpack_uint(buf[off:off + nidx], ib, nb * kb).reshape(nb, kb)
         off += nidx
         if int8:
             scale = _from_bytes(buf[off:], jnp.float32, nb)
@@ -347,39 +328,18 @@ def make_blocktopk_codec(ratio: float, block: int = 2048,
     return WireCodec(
         name=f"blocktopk_{ratio:g}_{value_dtype}", encode=encode,
         decode=decode, nbytes=nbytes,
-        compressor=make_blocktopk(ratio, block),
+        compressor=comp,
         exact=value_dtype == "float32",
         encode_from_selection=encode_from_selection,
         decode_to_selection=decode_to_selection,
         roundtrip_selection=roundtrip_selection)
 
 
-def _pack_sign_bits(bits_u8, pack_impl: str):
-    if pack_impl == "pallas":
-        from repro.kernels.bitpack import DEFAULT_BLOCK, pack_bits
-        n = bits_u8.size
-        pad = -n % DEFAULT_BLOCK
-        return pack_bits(jnp.pad(bits_u8, (0, pad)))[: (n + 7) // 8]
-    return jnp.packbits(bits_u8)
-
-
-def _unpack_sign_bits(buf, d: int, pack_impl: str):
-    if pack_impl == "pallas":
-        from repro.kernels.bitpack import DEFAULT_BLOCK, unpack_bits
-        pad = -buf.size % (DEFAULT_BLOCK // 8)
-        return unpack_bits(jnp.pad(buf, (0, pad)))[:d]
-    return jnp.unpackbits(buf, count=d)
-
-
-def make_sign_codec(block: int = 0, pack_impl: str = "jnp") -> WireCodec:
+def make_sign_codec(block: int = 0) -> WireCodec:
     """1 bit/coordinate + fp32 scale(s). ``block=0``: one global ‖x‖₁/d
     scale — the paper's Table 1 format and bit-exact vs ``make_sign``.
     ``block>0``: one scale per block of that size (beyond-paper; tighter
-    local scales at 32 bits/block extra). ``pack_impl="pallas"`` routes the
-    1-bit packing through the kernels.bitpack Pallas kernels (the TPU
-    hot-loop implementation; byte-identical to the default jnp path)."""
-    if pack_impl not in ("jnp", "pallas"):
-        raise ValueError(f"unknown pack_impl {pack_impl!r}")
+    local scales at 32 bits/block extra)."""
 
     def nb_of(d: int) -> int:
         return 1 if block <= 0 else -(-d // block)
@@ -399,12 +359,12 @@ def make_sign_codec(block: int = 0, pack_impl: str = "jnp") -> WireCodec:
         return jnp.concatenate([
             _header("sign", "float32", d, 0, max(block, 0)),
             _to_bytes(scales_of(flat, d)),
-            _pack_sign_bits((flat >= 0).astype(jnp.uint8), pack_impl)])
+            jnp.packbits((flat >= 0).astype(jnp.uint8))])
 
     def decode(buf, d: int):
         nb = nb_of(d)
         scales = _from_bytes(buf[HEADER_BYTES:], jnp.float32, nb)
-        bits = _unpack_sign_bits(buf[HEADER_BYTES + 4 * nb:], d, pack_impl)
+        bits = jnp.unpackbits(buf[HEADER_BYTES + 4 * nb:], count=d)
         sgn = bits.astype(jnp.float32) * 2.0 - 1.0
         if block <= 0:
             return scales[0] * sgn
@@ -428,17 +388,16 @@ def make_sign_codec(block: int = 0, pack_impl: str = "jnp") -> WireCodec:
 
 
 def make_wire_codec(name: str, ratio: float = 1 / 64, block: int = 2048,
-                    value_dtype: str = "float32",
-                    pack_impl: str = "jnp") -> WireCodec:
+                    value_dtype: str = "float32") -> WireCodec:
     """Registry mirroring :func:`repro.core.compressors.make_compressor`."""
     if name in ("none", "identity", "dense32"):
         return make_dense32_codec()
     if name == "topk":
         return make_topk_codec(ratio, value_dtype)
     if name == "blocktopk":
-        return make_blocktopk_codec(ratio, block, value_dtype, pack_impl)
+        return make_blocktopk_codec(ratio, block, value_dtype)
     if name in ("sign", "packedsign"):
-        return make_sign_codec(pack_impl=pack_impl)
+        return make_sign_codec()
     raise ValueError(
         f"no wire codec for compressor {name!r} (randk/int8 deltas have no "
         f"packed format yet — run them with wire=False)")

@@ -314,7 +314,6 @@ class FedConfig:
     wire: bool = False
     wire_value_dtype: str = "float32"  # float32 = bit-exact vs the dense path
     wire_block: int = 2048         # codec block size (blocktopk/bitpack)
-    wire_pack_impl: str = "jnp"    # jnp | pallas — sub-word bit packing path
     # FedSim: process the per-client train/compress/encode pipeline in
     # chunks of this many clients (lax.scan over n/client_chunk chunks), so
     # peak delta memory is (client_chunk, d) instead of (n, d). 0 = off.
@@ -411,7 +410,6 @@ class FedConfig:
                 "shard_server_state — the blockscale layout does not "
                 "slice along the state-shard axes")
         check("local_opt", self.local_opt, FED_LOCAL_OPTS)
-        check("wire_pack_impl", self.wire_pack_impl, ("jnp", "pallas"))
         check("sparse_uplink", self.sparse_uplink, (None, True, False))
         if self.sparse_uplink and self.compressor not in ("topk",
                                                           "blocktopk"):

@@ -17,17 +17,11 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import FedConfig, TrainConfig
-from repro.core.mesh import (build_fed_round, fed_batch_defs,
-                             fed_state_defs, init_fed_state,
-                             mesh_metric_specs)
+from repro.core.mesh import init_fed_state, jit_fed_round
 from repro.core.sim import FedSim
 from repro.core.sampling import sample_clients
-from repro.models import params as pdefs
-from repro.sharding.rules import ParallelContext
 
 
 @dataclass
@@ -65,32 +59,18 @@ class FederatedTrainer:
             tp = dict(zip(self.mesh.axis_names,
                           self.mesh.devices.shape)).get("model", 1)
             assert self.model is not None and self.model.tp == tp
-            sizes = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
-            hierarchical = "data" not in self.fed.client_axes
-            ctx = ParallelContext(
-                # name the axis even at size 1: vma tracking needs the psum
-                # to prove replication over a mesh axis that exists
-                model_axis="model" if "model" in sizes else None, tp=tp,
-                data_axis="data" if (hierarchical and "data" in sizes) else None,
-                dp=sizes.get("data", 1) if hierarchical else 1,
-                client_axes=self.fed.client_axes,
-                num_clients=self.fed.num_clients,
-                tp_collective=self.train.tp_collective)
-            sdefs = fed_state_defs(self.model, self.fed)
-            ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
-            bdefs = fed_batch_defs(self.model, self.fed, self.train)
-            bsp = jax.tree.map(lambda d: d.spec, bdefs, is_leaf=pdefs.is_def)
-            rnd = build_fed_round(self.model, self.fed, self.train, ctx)
+            from repro.kernels.ops import default_kernel_impl
+            # the compiled kernels where they compile (TPU), jnp elsewhere
+            self._kernel_impl = default_kernel_impl()
             # state buffers are donated: FedMeshState (params, opt moments,
             # per-client EF errors) updates in place round over round
-            self._step = jax.jit(compat.shard_map(
-                rnd, mesh=self.mesh, in_specs=(ssp, bsp, P()),
-                out_specs=(ssp, mesh_metric_specs(self.fed))),
-                donate_argnums=(0,))
-            self._rnd, self._ssp, self._bsp = rnd, ssp, bsp
+            self._step = jit_fed_round(self.model, self.fed, self.train,
+                                       self.mesh,
+                                       kernel_impl=self._kernel_impl)
             self._scan_step = None
             self._state = init_fed_state(self.model, self.fed,
-                                         jax.random.PRNGKey(self.train.seed))
+                                         jax.random.PRNGKey(self.train.seed),
+                                         mesh=self.mesh)
 
     @property
     def params(self):
@@ -100,14 +80,9 @@ class FederatedTrainer:
         """Lazily build the scan-driven mesh step: R rounds of stacked
         batches/seeds scanned inside one shard_map (jit retraces per R)."""
         if self._scan_step is None:
-            from repro.core.mesh import (build_fed_rounds_scan,
-                                         scan_batch_specs)
-            self._scan_step = jax.jit(compat.shard_map(
-                build_fed_rounds_scan(self._rnd), mesh=self.mesh,
-                in_specs=(self._ssp, scan_batch_specs(self._bsp), P(None)),
-                out_specs=(self._ssp, mesh_metric_specs(self.fed,
-                                                        scan=True))),
-                donate_argnums=(0,))
+            self._scan_step = jit_fed_round(
+                self.model, self.fed, self.train, self.mesh,
+                kernel_impl=self._kernel_impl, scan=True)
         return self._scan_step
 
     def _stage_sim_rounds(self, rng, r0: int, count: int, batch_size: int):

@@ -7,7 +7,8 @@ contraction constant of Assumption 4.14, property-tested in
 ``tests/test_compressors.py``.
 
 ``blocktopk`` is the TPU-native variant (DESIGN.md §3): exact top-k' inside
-fixed-size blocks. Per block ‖C(x_b)−x_b‖² ≤ (1−k'/B)‖x_b‖², so the global
+fixed-size blocks (ties to the lowest index); its ``select`` lists each
+block's kept entries in ascending index order. Per block ‖C(x_b)−x_b‖² ≤ (1−k'/B)‖x_b‖², so the global
 contraction bound q = sqrt(1−r) is preserved.
 
 Sparse-friendly compressors (the top-k family) additionally expose
@@ -136,7 +137,9 @@ def make_blocktopk(ratio: float, block: int = 2048) -> Compressor:
             kept, idx = _argmax_select(xb)           # (nb,), (nb,)
             kept, idx = kept[:, None], idx[:, None]
         else:
-            _, idx = lax.top_k(jnp.abs(xb), k)       # (nb, k)
+            # the kept entries of each block in ascending index order —
+            # the order the Pallas topk_ef_sparse kernel emits them in
+            idx = jnp.sort(lax.top_k(jnp.abs(xb), k)[1], axis=1)   # (nb, k)
             kept = jnp.take_along_axis(xb, idx, axis=1)
         gidx = idx.astype(jnp.int32) + (jnp.arange(nb, dtype=jnp.int32)
                                         * bs)[:, None]

@@ -17,9 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm.faults import (FaultPlan, corrupt_selection,
                                mesh_corruption_plan, mesh_fault_mask)
 from repro.configs.base import FedConfig, TrainConfig
@@ -122,14 +121,43 @@ def fed_state_defs(model, fed: FedConfig):
         round=pdefs.ParamDef((), P(), dtype="int32", init="zeros"))
 
 
-def init_fed_state(model, fed: FedConfig, rng) -> FedMeshState:
+def init_fed_state(model, fed: FedConfig, rng, mesh=None) -> FedMeshState:
+    """The round-0 federated state. With ``mesh`` it is built under jit
+    with the state's own shardings as ``out_shardings``, so every device
+    materializes only its shard (no device ever holds the whole state)."""
     defs = fed_state_defs(model, fed)
-    params = pdefs.init_params(defs.params, rng)
-    zeros = lambda t: jax.tree.map(
-        lambda d: jnp.zeros(d.shape, jnp.dtype(d.dtype)), t, is_leaf=pdefs.is_def)
-    return FedMeshState(params=params, m=zeros(defs.m), v=zeros(defs.v),
-                        vhat=zeros(defs.vhat), errors=zeros(defs.errors),
-                        round=jnp.zeros((), jnp.int32))
+
+    def init(key):
+        params = pdefs.init_params(defs.params, key)
+        zeros = lambda t: jax.tree.map(
+            lambda d: jnp.zeros(d.shape, jnp.dtype(d.dtype)), t,
+            is_leaf=pdefs.is_def)
+        return FedMeshState(params=params, m=zeros(defs.m), v=zeros(defs.v),
+                            vhat=zeros(defs.vhat), errors=zeros(defs.errors),
+                            round=jnp.zeros((), jnp.int32))
+
+    if mesh is None:
+        return init(rng)
+    shardings = jax.tree.map(lambda d: NamedSharding(mesh, d.spec), defs,
+                             is_leaf=pdefs.is_def)
+    return jax.jit(init, out_shardings=shardings)(rng)
+
+
+def mesh_context(fed: FedConfig, mesh,
+                 tp_collective: str = "psum") -> ParallelContext:
+    """The :class:`ParallelContext` of a federated round on ``mesh``. Every
+    mesh axis is named even at size 1: the vma typing then proves the
+    round's outputs replicated over it. Without client axes (one client),
+    ``"data"`` is within-client data parallelism."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    hierarchical = "data" not in fed.client_axes and "data" in sizes
+    return ParallelContext(
+        model_axis="model" if "model" in sizes else None,
+        tp=sizes.get("model", 1),
+        data_axis="data" if hierarchical else None,
+        dp=sizes["data"] if hierarchical else 1,
+        client_axes=fed.client_axes, num_clients=fed.num_clients,
+        tp_collective=tp_collective)
 
 
 def _sharded_server_update(fed: FedConfig, st: ServerState, params, agg,
@@ -371,7 +399,7 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             if not fed.client_axes:
                 return t
             return jax.tree.map(
-                lambda x: compat.pvary(x, tuple(fed.client_axes)), t)
+                lambda x: lax.pvary(x, tuple(fed.client_axes)), t)
 
         local0 = _pvary(params)
 
@@ -499,6 +527,12 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
         loss = ctx.pmean_clients(loss_local)
         if hierarchical:
             loss = ctx.pmean_data(loss)
+        # any mesh axis the loss is still typed as varying over is one the
+        # round does not split it on (an unnamed size-1 axis, say): its
+        # copies agree, so the mean is the value and the output replicated
+        rest = tuple(jax.typeof(loss).vma)
+        if rest:
+            loss = lax.pmean(loss, rest)
         new_state = FedMeshState(params=new_params, m=new_st.m, v=new_st.v,
                                  vhat=new_st.vhat, errors=errors,
                                  round=new_st.t)
@@ -539,6 +573,30 @@ def mesh_metric_specs(fed: FedConfig, *, scan: bool = False):
         specs["survivors"] = sp
         specs["rejected"] = sp
     return specs
+
+
+def jit_fed_round(model, fed: FedConfig, train: TrainConfig, mesh, *,
+                  kernel_impl=None, scan: bool = False, chunk: int = 2048):
+    """The jitted, shard_mapped round on ``mesh``: ``(state, batch, seed)
+    -> (state, metrics)``, or with ``scan=True`` the multi-round form of
+    :func:`build_fed_rounds_scan`. The state is donated, so it updates in
+    place. The one builder behind ``launch.train``, ``launch.steps`` and
+    ``core.api.FederatedTrainer``."""
+    ctx = mesh_context(fed, mesh, train.tp_collective)
+    rnd = build_fed_round(model, fed, train, ctx, chunk=chunk,
+                          kernel_impl=kernel_impl)
+    specs = lambda defs: jax.tree.map(lambda d: d.spec, defs,
+                                      is_leaf=pdefs.is_def)
+    ssp = specs(fed_state_defs(model, fed))
+    bsp = specs(fed_batch_defs(model, fed, train))
+    seed_spec = P()
+    if scan:
+        rnd, bsp, seed_spec = (build_fed_rounds_scan(rnd),
+                               scan_batch_specs(bsp), P(None))
+    return jax.jit(jax.shard_map(
+        rnd, mesh=mesh, in_specs=(ssp, bsp, seed_spec),
+        out_specs=(ssp, mesh_metric_specs(fed, scan=scan)),
+        check_vma=True), donate_argnums=(0,))
 
 
 def build_fed_rounds_scan(fed_round):

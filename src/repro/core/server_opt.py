@@ -27,6 +27,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.configs.base import FedConfig
 
@@ -109,6 +110,30 @@ def _state_is_quantized(v_tree) -> bool:
     return any(_is_quant(l) or l.dtype != jnp.float32 for l in leaves)
 
 
+def fedams_step(m, v, vh, d, *, eta: float, beta1: float, beta2: float,
+                eps: float, option: int):
+    """The FedAMS step on fp32 arrays: ``(increment, m, v, v̂)``. The one
+    op sequence every server path runs — jnp, fused ingest and the Pallas
+    kernels — so they agree bit for bit across programs and backends:
+
+    * the moments are written ``m + (1-β)·(d - m)``, one product per sum,
+      so a compiler that fuses a multiply into the add (XLA:CPU does)
+      can only fuse it one way, whichever program it sits in;
+      ``β·m + (1-β)·d`` leaves it a choice of two;
+    * option 1 scales by ``rsqrt(v̂)``, the form XLA rewrites ``m/√v̂``
+      into, so a Pallas kernel (which would divide) matches it;
+      option 2 divides by ``√v̂ + ε``."""
+    m2 = m + (1 - beta1) * (d - m)
+    v2 = v + (1 - beta2) * (jnp.square(d) - v)
+    if option == 1:
+        vh2 = jnp.maximum(jnp.maximum(vh, v2), eps)
+        inc = eta * m2 * lax.rsqrt(vh2)
+    else:
+        vh2 = jnp.maximum(vh, v2)
+        inc = eta * m2 / (jnp.sqrt(vh2) + eps)
+    return inc, m2, v2, vh2
+
+
 def server_update(fed: FedConfig, state: ServerState, params, delta):
     """One server step. Returns (new_params, new_state). Quantized v/v̂
     storage (bf16 arrays or :class:`QuantState` leaves) is dequantized to
@@ -156,6 +181,19 @@ def _server_update_f32(fed: FedConfig, state: ServerState, params, delta):
             lambda x, d: x + eta * d.astype(x.dtype), params, delta)
         return new_params, ServerState(state.m, state.v, state.vhat, t)
 
+    if algo in ("fedams", "fedcams", "fedamsgrad"):
+        option = _ingest_option(fed)
+        out = jax.tree.map(
+            lambda mm, vv, vh, d: fedams_step(
+                mm, vv, vh, d.astype(jnp.float32), eta=eta, beta1=b1,
+                beta2=b2, eps=eps, option=option),
+            state.m, state.v, state.vhat, delta)
+        is_out = lambda o: isinstance(o, tuple)
+        part = lambda i: jax.tree.map(lambda o: o[i], out, is_leaf=is_out)
+        new_params = jax.tree.map(lambda x, inc: x + inc.astype(x.dtype),
+                                  params, part(0))
+        return new_params, ServerState(part(1), part(2), part(3), t)
+
     m = jax.tree.map(lambda mm, d: b1 * mm + (1 - b1) * d.astype(jnp.float32),
                      state.m, delta)
 
@@ -173,31 +211,12 @@ def _server_update_f32(fed: FedConfig, state: ServerState, params, delta):
             lambda vv, d: b2 * vv + (1 - b2) * jnp.square(d.astype(jnp.float32)),
             state.v, delta)
 
-    if algo in ("fedadam", "fedyogi", "fedadagrad"):
-        vhat = state.vhat  # unused
-        new_params = jax.tree.map(
-            lambda x, mm, vv: x + (eta * mm / (jnp.sqrt(vv) + eps)).astype(x.dtype),
-            params, m, v)
-    elif algo == "fedamsgrad":                       # Option 2
-        vhat = jax.tree.map(jnp.maximum, state.vhat, v)
-        new_params = jax.tree.map(
-            lambda x, mm, vh: x + (eta * mm / (jnp.sqrt(vh) + eps)).astype(x.dtype),
-            params, m, vhat)
-    elif algo in ("fedams", "fedcams"):
-        if fed.option == 1:                          # Option 1 (max stabilization)
-            vhat = jax.tree.map(
-                lambda vh, vv: jnp.maximum(jnp.maximum(vh, vv), eps),
-                state.vhat, v)
-            new_params = jax.tree.map(
-                lambda x, mm, vh: x + (eta * mm / jnp.sqrt(vh)).astype(x.dtype),
-                params, m, vhat)
-        else:                                        # Option 2
-            vhat = jax.tree.map(jnp.maximum, state.vhat, v)
-            new_params = jax.tree.map(
-                lambda x, mm, vh: x + (eta * mm / (jnp.sqrt(vh) + eps)).astype(x.dtype),
-                params, m, vhat)
-    else:
+    if algo not in ("fedadam", "fedyogi", "fedadagrad"):
         raise ValueError(f"unknown algorithm {algo!r}")
+    vhat = state.vhat  # unused
+    new_params = jax.tree.map(
+        lambda x, mm, vv: x + (eta * mm / (jnp.sqrt(vv) + eps)).astype(x.dtype),
+        params, m, v)
 
     return new_params, ServerState(m, v, vhat, t)
 
@@ -242,12 +261,10 @@ def server_ingest_leaf(fed: FedConfig, x, m, v, vh, vals, idx, n_div, *,
     state_dtype = ("int8" if _is_quant(v) else str(jnp.dtype(v.dtype)))
 
     if impl == "kernel":
-        from repro.kernels.bitpack import _resolve_interpret
         from repro.kernels.fedams_ingest import fedams_ingest
         kw = dict(n_div=n_div, eta=fed.eta, beta1=fed.beta1, beta2=fed.beta2,
                   eps=fed.eps, option=option, block=block,
-                  state_dtype=state_dtype,
-                  interpret=_resolve_interpret(interpret))
+                  state_dtype=state_dtype, interpret=interpret)
         if state_dtype == "int8":
             x2, m2, qv, qvh, sv, svh = fedams_ingest(
                 x, m, v.q, vh.q, vals3, idx3, v.scale, vh.scale, **kw)
@@ -269,15 +286,10 @@ def server_ingest_leaf(fed: FedConfig, x, m, v, vh, vals, idx, n_div, *,
     else:
         vv = v.astype(jnp.float32).reshape(nb, block)
         vhd = vh.astype(jnp.float32).reshape(nb, block)
-    b1, b2, eta, eps = fed.beta1, fed.beta2, fed.eta, fed.eps
-    m2 = b1 * mb + (1 - b1) * dm
-    v2 = b2 * vv + (1 - b2) * jnp.square(dm)
-    if option == 1:
-        vh2 = jnp.maximum(jnp.maximum(vhd, v2), eps)
-        x2 = xb + eta * m2 / jnp.sqrt(vh2)
-    else:
-        vh2 = jnp.maximum(vhd, v2)
-        x2 = xb + eta * m2 / (jnp.sqrt(vh2) + eps)
+    inc, m2, v2, vh2 = fedams_step(mb, vv, vhd, dm, eta=fed.eta,
+                                   beta1=fed.beta1, beta2=fed.beta2,
+                                   eps=fed.eps, option=option)
+    x2 = xb + inc
     if state_dtype == "int8":
         return (x2.reshape(-1), m2.reshape(-1),
                 _requant_flat(v2, nb), _requant_flat(vh2, nb))

@@ -153,10 +153,10 @@ class FedSim:
                     and self.comp.name.startswith("blocktopk")
                     and not fed.track_gamma and not chunked
                     and fed.agg_groups <= 1 and self.faults is None)
-        from repro.kernels.bitpack import _resolve_interpret
+        from repro.kernels.common import resolve_interpret
         self._fused = resolve_fused_ingest(
             fed, eligible=eligible, have_kernel=True,
-            compiled=not _resolve_interpret(None),
+            compiled=not resolve_interpret(None),
             detail="FedSim fuses only the unchunked sparse blocktopk "
                    "uplink with track_gamma=False (the γ diagnostic and "
                    "the client_chunk scan both consume a dense aggregate) "
@@ -181,8 +181,7 @@ class FedSim:
                                     make_dense32_codec, make_wire_codec)
             name = fed.compressor if self.comp is not None else "dense32"
             self.codec = make_wire_codec(name, fed.compress_ratio,
-                                         fed.wire_block, fed.wire_value_dtype,
-                                         fed.wire_pack_impl)
+                                         fed.wire_block, fed.wire_value_dtype)
             self._down_codec = (self.codec if fed.two_way
                                 else make_dense32_codec())
             self.network = network or SimulatedNetwork(

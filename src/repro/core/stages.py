@@ -274,8 +274,8 @@ def resolve_mesh_sparse_impl(fed: FedConfig, kernel_impl) -> str:
     """``fed.mesh_sparse_impl`` → the selection provider that will run:
     ``"kernel"`` (fused Pallas ``topk_ef_sparse`` via
     ``KernelImpl.topk_select_tree``) or ``"jnp"`` (``Compressor.select``).
-    ``auto`` picks the kernel only when it would compile (TPU) — off-TPU
-    the interpreter loses to compiled XLA, so auto falls back to jnp even
+    ``auto`` picks the kernel only when it compiles — on the CPU platform
+    the interpreter loses to compiled XLA, so auto picks jnp even
     when a KernelImpl is supplied (it still serves the dense-hat
     ``ef_compress_tree`` path)."""
     impl = fed.mesh_sparse_impl

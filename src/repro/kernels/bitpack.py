@@ -1,4 +1,4 @@
-"""n-bit pack/unpack as Pallas TPU kernels (the wire formats' hot loop).
+"""n-bit pack/unpack, word-wise (the wire formats' hot loop).
 
 The wire formats (comm.wire) carry sub-word payloads: 1 bit per coordinate
 for the sign codec, ``ceil(log2(B))`` bits per kept index for blocktopk
@@ -6,7 +6,8 @@ for the sign codec, ``ceil(log2(B))`` bits per kept index for blocktopk
 byte-shuffle that on TPU should stream HBM→VMEM once per tile — the naive
 formulation (expand every value to an ``(count, nbits)`` bit matrix, then
 ``packbits``) materializes an 8–32× larger intermediate, which is exactly
-the memory traffic the wire format exists to avoid.
+the memory traffic the wire format exists to avoid. XLA compiles these
+shift/or forms for any backend; no Pallas kernel is needed.
 
 Both directions here are *word-wise shift/or accumulations* with no bit
 matrix. MSB-first at ``nbits`` each, value slot ``s`` of the stream spans
@@ -18,37 +19,20 @@ alignment is the *constant* shift ``8k + 8 − (s+1)·nbits``, so
     value_s = OR_k shift(byte_k, (s+1)·nbits − 8k − 8)   & (2^nbits − 1)
 
 With ``L = lcm(nbits, 8)`` the stream tiles into groups of ``L/nbits``
-values ↔ ``L/8`` bytes, making the (k, s) pairs a small static table the
-kernels unroll (≤ ``L/8 · (⌈8/nbits⌉+1)`` shift/or ops per group).
+values ↔ ``L/8`` bytes, making the (k, s) pairs a small static table
+(≤ ``L/8 · (⌈8/nbits⌉+1)`` shift/or ops per group).
 
-``pack_uint_words`` / ``unpack_uint_words`` are the pure-jnp form of the
-same algorithm — the oracle the kernels are validated against in
-tests/test_wire.py, and the default path ``comm.wire`` uses under jit.
-``pack_bits_ref`` / ``unpack_bits_ref`` are the original 1-bit oracles.
-
-``interpret=None`` (default) selects the backend automatically: compiled
-Pallas on TPU, interpreter everywhere else — so the "pallas" wire paths
-run the real kernels exactly where Pallas can compile them.
+``pack_uint_words`` / ``unpack_uint_words`` are the path ``comm.wire``
+uses; ``pack_bits_ref`` / ``unpack_bits_ref`` are the 1-bit oracles they
+are validated against in tests/test_wire.py.
 """
 from __future__ import annotations
 
-import functools
 import math
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-DEFAULT_BLOCK = 2048  # bits per grid step for the 1-bit API (multiple of 8)
 
 _WEIGHTS = (128, 64, 32, 16, 8, 4, 2, 1)  # MSB-first, like jnp.packbits
-
-
-def _resolve_interpret(interpret):
-    """Backend-aware default: compile on TPU, interpret elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def group_shape(nbits: int):
@@ -147,103 +131,3 @@ def unpack_bits_ref(packed):
     p = packed.astype(jnp.int32)
     shifts = jnp.arange(7, -1, -1, dtype=jnp.int32)
     return ((p[:, None] >> shifts) & 1).reshape(-1).astype(jnp.uint8)
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernels
-# ---------------------------------------------------------------------------
-
-
-def _pack_uint_kernel(v_ref, out_ref, *, nbits: int, rows: int):
-    gv, gb = group_shape(nbits)
-    v = v_ref[...].reshape(rows, gv).astype(jnp.uint32) & _umask(nbits)
-    cols = []
-    for pairs in _pack_pairs(nbits):
-        acc = jnp.zeros((rows,), jnp.uint32)
-        for s, sh in pairs:
-            acc = acc | _shl(v[:, s], sh)
-        cols.append(acc & 0xFF)
-    out_ref[...] = jnp.stack(cols, axis=1).reshape(-1).astype(jnp.uint8)
-
-
-def _unpack_uint_kernel(p_ref, out_ref, *, nbits: int, rows: int):
-    gv, gb = group_shape(nbits)
-    b = p_ref[...].reshape(rows, gb).astype(jnp.uint32)
-    mask = _umask(nbits)
-    cols = []
-    for pairs in _unpack_pairs(nbits):
-        acc = jnp.zeros((rows,), jnp.uint32)
-        for k, sh in pairs:
-            acc = acc | _shl(b[:, k], -sh)
-        cols.append(acc & mask)
-    out_ref[...] = jnp.stack(cols, axis=1).reshape(-1)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "group_block", "interpret"))
-def pack_uint(vals, nbits: int, *, group_block: int = 256,
-              interpret=None) -> jnp.ndarray:
-    """Pallas form of :func:`pack_uint_words`: vals (count,) uints
-    < 2**nbits -> ceil(count*nbits/8) bytes. Pads internally to whole grid
-    steps of ``group_block`` stream groups; byte-identical to the jnp path.
-    """
-    flat = vals.reshape(-1).astype(jnp.uint32)
-    count = flat.size
-    gv, gb = group_shape(nbits)
-    groups = -(-count // gv)
-    gpad = -(-groups // group_block) * group_block
-    v = jnp.pad(flat, (0, gpad * gv - count))
-    out = pl.pallas_call(
-        functools.partial(_pack_uint_kernel, nbits=nbits, rows=group_block),
-        grid=(gpad // group_block,),
-        in_specs=[pl.BlockSpec((group_block * gv,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((group_block * gb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((gpad * gb,), jnp.uint8),
-        interpret=_resolve_interpret(interpret),
-    )(v)
-    return out[: (count * nbits + 7) // 8]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "count", "group_block",
-                                    "interpret"))
-def unpack_uint(buf, nbits: int, count: int, *, group_block: int = 256,
-                interpret=None) -> jnp.ndarray:
-    """Pallas inverse of :func:`pack_uint`: read ``count`` uint32 values."""
-    flat = buf.reshape(-1)
-    gv, gb = group_shape(nbits)
-    groups = -(-count // gv)
-    gpad = -(-groups // group_block) * group_block
-    b = jnp.pad(flat, (0, max(gpad * gb - flat.size, 0)))[: gpad * gb]
-    out = pl.pallas_call(
-        functools.partial(_unpack_uint_kernel, nbits=nbits, rows=group_block),
-        grid=(gpad // group_block,),
-        in_specs=[pl.BlockSpec((group_block * gb,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((group_block * gv,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((gpad * gv,), jnp.uint32),
-        interpret=_resolve_interpret(interpret),
-    )(b)
-    return out[:count]
-
-
-# -- 1-bit API (the sign codec's path; nbits=1 specialization) --------------
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def pack_bits(bits, *, block: int = DEFAULT_BLOCK, interpret=None):
-    """bits: (N,) uint8 in {0,1} with N % block == 0, block % 8 == 0.
-    Returns (N/8,) uint8, identical to ``pack_bits_ref``."""
-    assert bits.ndim == 1 and block % 8 == 0
-    n = bits.shape[0]
-    assert n % block == 0, (n, block)
-    return pack_uint(bits, 1, group_block=block // 8, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def unpack_bits(packed, *, block: int = DEFAULT_BLOCK, interpret=None):
-    """packed: (M,) uint8 with 8*M % block == 0. Returns (8*M,) uint8."""
-    assert packed.ndim == 1 and block % 8 == 0
-    m = packed.shape[0]
-    assert (8 * m) % block == 0, (m, block)
-    return unpack_uint(packed, 1, 8 * m, group_block=block // 8,
-                       interpret=interpret).astype(jnp.uint8)

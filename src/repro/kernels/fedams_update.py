@@ -5,20 +5,15 @@ outputs — the unfused jnp version reads/writes each array separately (9+
 passes). The update is purely elementwise so it tiles trivially: 1-D blocks
 sized to keep 9 fp32 streams resident in VMEM.
 
-Implements both paper options with the jnp ``server_update``'s exact op
-sequence (``eta·m/√v̂`` is a true division, not a rsqrt multiply, and the
-v update is ``(1-β₂)·square(δ)`` — the left-associated form is 1 ulp
-off): m/v/v̂ are bit-identical to ``server_update`` everywhere, and x is
-bit-identical when both programs compile at the same shape; across
-differently-shaped programs XLA may contract the x division into an
-FMA/rsqrt form, a few ulp of each increment (regression-tested both ways
-in tests/test_server_opt.py):
-  option 1:  v̂ = max(v̂, v, ε);  x += η·m/√v̂
+Implements both paper options with ``server_update``'s op sequence
+(:func:`repro.core.server_opt.fedams_step`), so x/m/v/v̂ agree with it bit
+for bit:
+  option 1:  v̂ = max(v̂, v, ε);  x += η·m·rsqrt(v̂)
   option 2:  v̂ = max(v̂, v);     x += η·m/(√v̂+ε)
 
 Ragged sizes are handled by zero-padding the operands to a block multiple
 and slicing the outputs back: pad lanes carry d=0 so every output pad lane
-is a constant (m2=0, v2=0, x2=0) that the slice discards.
+is a constant that the slice discards.
 """
 from __future__ import annotations
 
@@ -28,6 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.server_opt import fedams_step
+from repro.kernels.common import interpret_arg, out_struct
+
 DEFAULT_BLOCK = 4096
 
 
@@ -35,16 +33,10 @@ def _fedams_kernel(x_ref, m_ref, v_ref, vh_ref, d_ref,
                    x_out, m_out, v_out, vh_out, *,
                    eta: float, beta1: float, beta2: float, eps: float,
                    option: int):
-    d = d_ref[...]
-    m2 = beta1 * m_ref[...] + (1.0 - beta1) * d
-    v2 = beta2 * v_ref[...] + (1.0 - beta2) * jnp.square(d)
-    if option == 1:
-        vh2 = jnp.maximum(jnp.maximum(vh_ref[...], v2), eps)
-        x2 = x_ref[...] + eta * m2 / jnp.sqrt(vh2)
-    else:
-        vh2 = jnp.maximum(vh_ref[...], v2)
-        x2 = x_ref[...] + eta * m2 / (jnp.sqrt(vh2) + eps)
-    x_out[...] = x2
+    inc, m2, v2, vh2 = fedams_step(m_ref[...], v_ref[...], vh_ref[...],
+                                   d_ref[...], eta=eta, beta1=beta1,
+                                   beta2=beta2, eps=eps, option=option)
+    x_out[...] = x_ref[...] + inc
     m_out[...] = m2
     v_out[...] = v2
     vh_out[...] = vh2
@@ -54,7 +46,7 @@ def _fedams_kernel(x_ref, m_ref, v_ref, vh_ref, d_ref,
                                              "option", "block", "interpret"))
 def fedams_update(x, m, v, vhat, delta, *, eta: float, beta1: float,
                   beta2: float, eps: float, option: int = 1,
-                  block: int = DEFAULT_BLOCK, interpret: bool = True):
+                  block: int = DEFAULT_BLOCK, interpret=None):
     """All inputs (N,) fp32, any N. Returns (x, m, v, vhat)."""
     n = x.shape[0]
     pad = (-n) % block
@@ -64,8 +56,7 @@ def fedams_update(x, m, v, vhat, delta, *, eta: float, beta1: float,
     np_ = n + pad
     grid = (np_ // block,)
     spec = pl.BlockSpec((block,), lambda i: (i,))
-    out_shape = tuple(jax.ShapeDtypeStruct((np_,), jnp.float32)
-                      for _ in range(4))
+    out_shape = tuple(out_struct((np_,), jnp.float32, x) for _ in range(4))
     outs = pl.pallas_call(
         functools.partial(_fedams_kernel, eta=eta, beta1=beta1, beta2=beta2,
                           eps=eps, option=option),
@@ -73,7 +64,8 @@ def fedams_update(x, m, v, vhat, delta, *, eta: float, beta1: float,
         in_specs=[spec] * 5,
         out_specs=[spec] * 4,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_arg(interpret),
+        name="fedams_update",
     )(x, m, v, vhat, delta)
     if pad:
         outs = tuple(o[:n] for o in outs)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import FedConfig
 from repro.core.compressors import Compressor, Selection
 from repro.core.server_opt import ServerState
-from repro.kernels.bitpack import _resolve_interpret
+from repro.kernels.common import resolve_interpret
 from repro.kernels.fedams_update import fedams_update as _fedams_update
 from repro.kernels.sign_ef import sign_ef as _sign_ef
 from repro.kernels.topk_ef import topk_ef as _topk_ef
@@ -36,22 +36,22 @@ def _pad_flat(x, block):
 
 @dataclass(frozen=True)
 class KernelImpl:
-    """``interpret=None`` (the default) resolves per backend exactly like
-    ``kernels.bitpack``: compiled Pallas on TPU, interpreter elsewhere —
-    so constructing a ``KernelImpl`` on TPU runs the real kernels without
-    the caller having to know about interpret mode."""
+    """``interpret=None`` (the default) lets the platform decide
+    (:func:`~repro.kernels.common.resolve_interpret`): the interpreter on
+    the CPU platform only, compiled Pallas everywhere else — so a
+    ``KernelImpl`` on TPU runs the real kernels or fails to compile."""
 
     block: int = 2048
     interpret: Optional[bool] = None
 
     @property
     def _interp(self) -> bool:
-        return _resolve_interpret(self.interpret)
+        return resolve_interpret(self.interpret)
 
     @property
     def compiled(self) -> bool:
-        """True when the kernels run as compiled Pallas (TPU) rather than
-        the interpreter — what ``mesh_sparse_impl='auto'`` keys off."""
+        """True when the kernels run as compiled Pallas rather than the
+        interpreter — what ``mesh_sparse_impl='auto'`` keys off."""
         return not self._interp
 
     # -- error-feedback compression ------------------------------------
@@ -91,9 +91,9 @@ class KernelImpl:
         (DESIGN.md §3): one HBM pass per tile emits the compacted block.
         ``mesh_uplink``'s sparse aggregation routes through it (via
         :meth:`topk_select_tree`) when ``fed.mesh_sparse_impl`` resolves
-        to the kernel; the sim backend and the off-TPU mesh default use
+        to the kernel; the sim backend and the CPU-platform mesh use
         the jnp ``Compressor.select`` (compiled XLA beats interpret-mode
-        Pallas off-TPU)."""
+        Pallas on the CPU)."""
         from repro.core.compressors import block_layout
         bs, _ = block_layout(x.size, self.block)
         flat, n = _pad_flat(x, bs)
@@ -189,3 +189,13 @@ class KernelImpl:
         return server_ingest_tree(fed, st, params, sels, n_div, gather,
                                   block=self.block, impl="kernel",
                                   interpret=self.interpret)
+
+
+def default_kernel_impl(forced: bool = False) -> Optional[KernelImpl]:
+    """The kernels an entry point runs with no flag set: a
+    :class:`KernelImpl` on TPU, where they compile, so ``auto`` resolves
+    to them; ``None`` on the CPU platform, where the interpreter would
+    lose to compiled XLA — unless ``forced``."""
+    if forced or jax.default_backend() == "tpu":
+        return KernelImpl()
+    return None

@@ -44,15 +44,10 @@ def sign_ef_ref(x, err):
 def fedams_update_ref(x, m, v, vhat, delta, *, eta: float, beta1: float,
                       beta2: float, eps: float, option: int = 1):
     """Fused FedAMS server update on flat fp32 vectors."""
-    m2 = beta1 * m + (1 - beta1) * delta
-    v2 = beta2 * v + (1 - beta2) * jnp.square(delta)
-    if option == 1:
-        vh2 = jnp.maximum(jnp.maximum(vhat, v2), eps)
-        x2 = x + eta * m2 / jnp.sqrt(vh2)
-    else:
-        vh2 = jnp.maximum(vhat, v2)
-        x2 = x + eta * m2 / (jnp.sqrt(vh2) + eps)
-    return x2, m2, v2, vh2
+    from repro.core.server_opt import fedams_step
+    inc, m2, v2, vh2 = fedams_step(m, v, vhat, delta, eta=eta, beta1=beta1,
+                                   beta2=beta2, eps=eps, option=option)
+    return x + inc, m2, v2, vh2
 
 
 def fedams_ingest_ref(x, m, v, vhat, vals, idx, v_scale=None, vh_scale=None,
@@ -61,19 +56,22 @@ def fedams_ingest_ref(x, m, v, vhat, vals, idx, v_scale=None, vh_scale=None,
                       state_dtype: str = "float32"):
     """One-pass sparse ingest oracle, same contract as ``fedams_ingest``.
 
-    The scatter accumulates client-major — a per-client loop of
-    unique-index scatter-adds, the same accumulation order as the kernel's
-    client fori_loop (kernel ≡ ref bitwise). XLA's single flat scatter-add
-    may reassociate collided updates, so vs the two-pass baseline this
-    oracle is within ≤1 ulp on collided coordinates. The elementwise
-    FedAMS step runs in fp32 with dequant/requant of the stored second
-    moments. Returns ``(x, m, v, vhat)`` (+ scales for int8).
+    The mean delta is summed client by client: each client's entries
+    (distinct positions) are set into a fresh dense vector and the vectors
+    are added in client order — the kernel's order, and a chain of dense
+    adds no compiler reassociates (kernel ≡ ref bitwise). XLA's single
+    flat scatter-add may reassociate collided updates, so on coordinates
+    several clients selected this oracle may differ from the two-pass
+    baseline by that rounding. The elementwise FedAMS step runs in fp32
+    with dequant/requant of the stored second moments. Returns
+    ``(x, m, v, vhat)`` (+ scales for int8).
     """
     n, nb, k = vals.shape
     N = x.shape[0]
     acc = jnp.zeros(N, jnp.float32)
-    for j in range(n):   # client-major; within a client indices are unique
-        acc = acc.at[idx[j].reshape(-1)].add(vals[j].reshape(-1))
+    for j in range(n):   # client order; within a client indices are unique
+        acc = acc + jnp.zeros(N, jnp.float32).at[idx[j].reshape(-1)].set(
+            vals[j].reshape(-1))
     d = acc / n_div
     if state_dtype == "int8":
         vv = (v.astype(jnp.float32).reshape(nb, block)

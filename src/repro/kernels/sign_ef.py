@@ -17,49 +17,58 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import interpret_arg, out_struct, row_grid
+
 DEFAULT_BLOCK = 2048
 
 
 def _l1_partial_kernel(x_ref, e_ref, out_ref):
-    out_ref[...] = jnp.sum(jnp.abs(x_ref[...] + e_ref[...]))[None]
+    out_ref[...] = jnp.sum(jnp.abs(x_ref[...] + e_ref[...]), axis=1,
+                           keepdims=True)
 
 
 def _sign_ef_kernel(scale_ref, x_ref, e_ref, hat_ref, err_ref):
     tot = x_ref[...] + e_ref[...]
-    scale = scale_ref[0]
     # sign(0) := +1, matching make_sign and the 1-bit wire format (a 1-bit
     # lane cannot carry a third "zero" state)
-    hat = scale * jnp.where(tot >= 0, 1.0, -1.0)
+    hat = scale_ref[...] * jnp.where(tot >= 0, 1.0, -1.0)
     hat_ref[...] = hat
     err_ref[...] = tot - hat
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def sign_ef(x, err, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
-    """x, err: (N,) fp32 with N % block == 0. Returns (hat, new_err)."""
+def sign_ef(x, err, *, block: int = DEFAULT_BLOCK, interpret=None):
+    """x, err: (N,) fp32 with N % block == 0 (block % 128 == 0 to
+    compile for TPU).
+    Returns (hat, new_err)."""
     assert x.ndim == 1 and x.shape == err.shape
     n = x.shape[0]
     assert n % block == 0, (n, block)
-    grid = (n // block,)
-    spec = pl.BlockSpec((block,), lambda i: (i,))
+    nb = n // block
+    grid, r = row_grid(nb)
+    mat = (nb, block)
+    spec = pl.BlockSpec((r, block), lambda i: (i, 0))
+    interpret = interpret_arg(interpret)
+    xm, em = x.reshape(mat), err.reshape(mat)
 
     partials = pl.pallas_call(
         _l1_partial_kernel,
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid[0],), x.dtype),
+        out_specs=pl.BlockSpec((r, 1), lambda i: (i, 0)),
+        out_shape=out_struct((nb, 1), x.dtype, x),
         interpret=interpret,
-    )(x, err)
-    scale = (jnp.sum(partials) / n).reshape(1)
+        name="sign_ef_l1",
+    )(xm, em)
+    scale = (jnp.sum(partials) / n).reshape(1, 1)
 
-    out_shape = (jax.ShapeDtypeStruct(x.shape, x.dtype),
-                 jax.ShapeDtypeStruct(x.shape, x.dtype))
-    return pl.pallas_call(
+    hat, ne = pl.pallas_call(
         _sign_ef_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,)), spec, spec],
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), spec, spec],
         out_specs=[spec, spec],
-        out_shape=out_shape,
+        out_shape=(out_struct(mat, x.dtype, x), out_struct(mat, x.dtype, x)),
         interpret=interpret,
-    )(scale, x, err)
+        name="sign_ef",
+    )(scale, xm, em)
+    return hat.reshape(-1), ne.reshape(-1)

@@ -1,4 +1,6 @@
 import os
+# the dry-run compiles on 512 host CPU devices, never on an attached chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
@@ -54,10 +56,13 @@ def main() -> None:
     from repro.configs import ARCH_IDS, INPUT_SHAPES, FedConfig, TrainConfig
     from repro.configs.registry import get_arch
     from repro.launch.hlo_analysis import analyze
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import backend_spec, make_production_mesh
     from repro.launch.roofline import model_flops_for, roofline_from_hlo
     from repro.launch.steps import build_step, shape_allowed
 
+    # the production meshes are v5e pods: their roofline terms are a
+    # model against that chip's published peaks, not a measurement
+    target = backend_spec("TPU v5 lite")
     archs = ARCH_IDS if args.arch == "all" else [args.arch]
     shapes = list(INPUT_SHAPES) if args.shape == "all" else [args.shape]
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
@@ -147,7 +152,8 @@ def main() -> None:
                     else:
                         mf = model_flops_for(bundle.model.cfg, "decode",
                                              shape.global_batch)
-                    rl = roofline_from_hlo(hc, chips=chips, model_flops=mf)
+                    rl = roofline_from_hlo(hc, chips=chips, model_flops=mf,
+                                           spec=target)
                     results[key] = {
                         "status": "ok",
                         "description": bundle.description,

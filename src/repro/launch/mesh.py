@@ -9,71 +9,52 @@ import — see launch/dryrun.py).
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 
 
 @dataclasses.dataclass(frozen=True)
 class BackendSpec:
-    """Per-chip roofline constants for one accelerator backend."""
+    """Per-chip roofline constants for one accelerator."""
     name: str
     peak_flops_bf16: float     # FLOP/s
     hbm_bw: float              # B/s
-    ici_bw_per_link: float     # B/s per link (host interconnect for cpu)
+    ici_bw_per_link: float     # B/s per link
 
 
-#: Known backends. The numbers are per chip; ``cpu`` is a rough stand-in
-#: for the container host (measurements on it are relative, not absolute).
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e: Google Cloud "TPU v5e" documentation (197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s ICI per chip over four links).
+#: TPU v4: Google Cloud "TPU v4" documentation.
 BACKEND_SPECS = {
-    "tpu_v5e": BackendSpec("tpu_v5e", peak_flops_bf16=197e12,
-                           hbm_bw=819e9, ici_bw_per_link=50e9),
-    "tpu_v4": BackendSpec("tpu_v4", peak_flops_bf16=275e12,
+    "TPU v5 lite": BackendSpec("TPU v5 lite", peak_flops_bf16=197e12,
+                               hbm_bw=819e9, ici_bw_per_link=50e9),
+    "TPU v4": BackendSpec("TPU v4", peak_flops_bf16=275e12,
                           hbm_bw=1228e9, ici_bw_per_link=100e9),
-    "cpu": BackendSpec("cpu", peak_flops_bf16=2e12,
-                       hbm_bw=50e9, ici_bw_per_link=10e9),
 }
 
-DEFAULT_BACKEND = "tpu_v5e"
 
-
-def backend_spec(name: str | None = None) -> BackendSpec:
-    """Resolve a :class:`BackendSpec` by name; ``None`` reads the
-    ``REPRO_BACKEND`` env var and falls back to ``tpu_v5e`` (the paper's
-    reference part, and the historical hardwired constants)."""
-    name = name or os.environ.get("REPRO_BACKEND") or DEFAULT_BACKEND
+def backend_spec(device_kind: str | None = None) -> BackendSpec:
+    """Peaks for ``device_kind``; ``None`` reads the first local device.
+    A device that is not in :data:`BACKEND_SPECS` (the CPU among them) is
+    an error: no peak is ever assumed."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
     try:
-        return BACKEND_SPECS[name]
+        return BACKEND_SPECS[device_kind]
     except KeyError:
         raise ValueError(
-            f"unknown backend {name!r}: pick one of "
-            f"{sorted(BACKEND_SPECS)} (or extend BACKEND_SPECS)") from None
-
-
-# Back-compat module constants (tpu_v5e): existing call sites and §Perf
-# numbers keep their historical meaning.
-PEAK_FLOPS_BF16 = BACKEND_SPECS["tpu_v5e"].peak_flops_bf16
-HBM_BW = BACKEND_SPECS["tpu_v5e"].hbm_bw
-ICI_BW_PER_LINK = BACKEND_SPECS["tpu_v5e"].ici_bw_per_link
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """Version shim: ``jax.sharding.AxisType`` (and the ``axis_types=``
-    kwarg of ``jax.make_mesh``) only exist on newer jax; on 0.4.x every
-    mesh axis is implicitly Auto, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+            f"no published peaks for device kind {device_kind!r}: known "
+            f"kinds are {sorted(BACKEND_SPECS)}") from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small local runs)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

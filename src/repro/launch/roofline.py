@@ -6,11 +6,10 @@ Three terms per (arch × shape × mesh), per the brief:
     memory     = HLO_bytes_per_chip / hbm_bw
     collective = Σ collective_bytes × factor / ici_bw_per_link
 
-The bandwidth/peak constants come from a :class:`~repro.launch.mesh.
-BackendSpec` (``launch.mesh.BACKEND_SPECS``); the default is tpu_v5e
-(197e12 / 819e9 / 50e9 — the paper's reference part and the historical
-hardwired numbers), overridable per call via ``spec=`` or globally via
-the ``REPRO_BACKEND`` env var.
+The bandwidth/peak constants come from the :class:`~repro.launch.mesh.
+BackendSpec` of the target chip (``launch.mesh.BACKEND_SPECS``, keyed by
+``device_kind``), which every caller names: these terms are a model of
+that chip, never a measurement of it.
 
 ``cost_analysis()`` is the per-device SPMD program, so its flops/bytes are
 already per-chip. Collective bytes are parsed from the compiled HLO: the sum
@@ -26,8 +25,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict
 
-from repro.launch.mesh import (HBM_BW, ICI_BW_PER_LINK,  # noqa: F401
-                               PEAK_FLOPS_BF16, BackendSpec, backend_spec)
+from repro.launch.mesh import BackendSpec
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -112,19 +110,15 @@ def model_flops_for(cfg, shape_kind: str, tokens: float, local_steps: int = 1):
 
 
 def roofline_from_hlo(hc, *, chips: int, model_flops: float,
-                      spec: BackendSpec | None = None) -> Roofline:
+                      spec: BackendSpec) -> Roofline:
     """Preferred path: trip-count-aware HloCost from launch.hlo_analysis.
-
-    ``spec`` selects the backend bandwidth/peak constants; ``None`` keeps
-    the default resolution (``REPRO_BACKEND`` env var, else tpu_v5e — the
-    historical hardwired numbers)."""
+    ``spec`` holds the target chip's bandwidth/peak constants."""
     return _mk_roofline(hc.flops, hc.bytes, hc.weighted_coll_bytes,
                         chips=chips, model_flops=model_flops, spec=spec)
 
 
 def roofline_from(cost: Dict, stats: CollectiveStats, *, chips: int,
-                  model_flops: float,
-                  spec: BackendSpec | None = None) -> Roofline:
+                  model_flops: float, spec: BackendSpec) -> Roofline:
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = stats.weighted_bytes
@@ -133,8 +127,7 @@ def roofline_from(cost: Dict, stats: CollectiveStats, *, chips: int,
 
 
 def _mk_roofline(flops, hbm, coll, *, chips: int, model_flops: float,
-                 spec: BackendSpec | None = None) -> Roofline:
-    spec = spec or backend_spec()
+                 spec: BackendSpec) -> Roofline:
     compute_s = flops / spec.peak_flops_bf16
     memory_s = hbm / spec.hbm_bw
     collective_s = coll / spec.ici_bw_per_link

@@ -27,6 +27,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     if args.devices:
+        # a host mesh of forced CPU devices: never the chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
@@ -35,7 +37,6 @@ def main(argv=None) -> None:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.configs.registry import get_arch
     from repro.launch.mesh import make_mesh
     from repro.models import params as pdefs
@@ -64,7 +65,7 @@ def main(argv=None) -> None:
         cdefs = model.cache_defs(args.batch, max_len, seq_sharded=False)
         cspecs = jax.tree.map(lambda d: d.spec, cdefs, is_leaf=pdefs.is_def)
 
-        prefill = jax.jit(compat.shard_map(
+        prefill = jax.jit(jax.shard_map(
             lambda p, t: model.prefill(p, t, ctx, max_len=max_len),
             mesh=mesh, in_specs=(pspecs, P()),
             out_specs=(P("model"), cspecs)))
@@ -73,7 +74,7 @@ def main(argv=None) -> None:
             lg, c2 = model.decode_step(p, t, c, pos, ctx, max_len=max_len)
             return greedy_sample(lg, ctx), c2
 
-        decode = jax.jit(compat.shard_map(
+        decode = jax.jit(jax.shard_map(
             dstep, mesh=mesh, in_specs=(pspecs, P(), cspecs, P()),
             out_specs=(P(), cspecs)))
     else:

@@ -16,14 +16,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import (FedConfig, ModelConfig, ShapeConfig,
                                 TrainConfig)
 from repro.configs.registry import ArchSpec
-from repro.core.mesh import (build_fed_round, fed_batch_defs,
-                             fed_state_defs)
+from repro.core.mesh import fed_batch_defs, fed_state_defs, jit_fed_round
 from repro.models import params as pdefs
 from repro.models.model import Model
 from repro.sharding.rules import ParallelContext
@@ -54,18 +52,6 @@ def resolve_fed(spec: ArchSpec, fed: FedConfig, mesh) -> FedConfig:
         shards *= sizes[a]
     return dataclasses.replace(fed, client_axes=axes, num_clients=m,
                                state_shards=shards)
-
-
-def train_ctx(fed: FedConfig, mesh,
-              tp_collective: str = "psum") -> ParallelContext:
-    sizes = mesh_axis_sizes(mesh)
-    hierarchical = "data" not in fed.client_axes
-    return ParallelContext(
-        model_axis="model", tp=sizes.get("model", 1),
-        data_axis="data" if hierarchical else None,
-        dp=sizes.get("data", 1) if hierarchical else 1,
-        client_axes=fed.client_axes, num_clients=fed.num_clients,
-        tp_collective=tp_collective)
 
 
 def serve_ctx(mesh, *, seq_sharded: bool) -> ParallelContext:
@@ -144,20 +130,10 @@ def build_train_step(spec: ArchSpec, shape: ShapeConfig, mesh,
     train = dataclasses.replace(train, global_batch=shape.global_batch,
                                 seq_len=shape.seq_len)
     model = Model(cfg, tp=sizes.get("model", 1))
-    ctx = train_ctx(fed, mesh, train.tp_collective)
-
     sdefs = fed_state_defs(model, fed)
     bdefs = fed_batch_defs(model, fed, train)
-    state_specs, batch_specs = _specs(sdefs), _specs(bdefs)
-
-    rnd = build_fed_round(model, fed, train, ctx, chunk=chunk,
-                          kernel_impl=kernel_impl)
-    from repro.core.mesh import mesh_metric_specs
-    fn = jax.jit(compat.shard_map(
-        rnd, mesh=mesh,
-        in_specs=(state_specs, batch_specs, P()),
-        out_specs=(state_specs, mesh_metric_specs(fed)),
-        check_vma=True))
+    fn = jit_fed_round(model, fed, train, mesh, kernel_impl=kernel_impl,
+                       chunk=chunk)
     abstract = (pdefs.abstract_params(sdefs, mesh),
                 pdefs.abstract_params(bdefs, mesh),
                 jax.ShapeDtypeStruct((), jnp.int32))
@@ -189,7 +165,7 @@ def build_prefill_step(spec: ArchSpec, shape: ShapeConfig, mesh,
             return model.encode(params, batch, ctx, chunk=chunk)
 
         out_specs = P(bax, None, "model")
-        fn = jax.jit(compat.shard_map(step, mesh=mesh,
+        fn = jax.jit(jax.shard_map(step, mesh=mesh,
                                    in_specs=(param_specs, bspecs),
                                    out_specs=out_specs))
         abstract = (model.abstract_params(mesh),
@@ -209,7 +185,7 @@ def build_prefill_step(spec: ArchSpec, shape: ShapeConfig, mesh,
         return model.prefill(params, tokens, ctx, max_len=shape.seq_len,
                              chunk=chunk)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(param_specs, tok_def.spec),
         out_specs=(P(bax, "model"), cache_specs)))
@@ -242,7 +218,7 @@ def build_decode_step(spec: ArchSpec, shape: ShapeConfig, mesh,
         return model.decode_step(params, token, caches, pos, ctx,
                                  max_len=shape.seq_len)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(param_specs, tok_def.spec, cache_specs, P()),
         out_specs=(P(bax, "model"), cache_specs)))

@@ -13,16 +13,21 @@ from __future__ import annotations
 
 import argparse
 import os
-import sys
 import time
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> dict:
+    """Parse ``argv``, train, and return ``{"history", "compile_s",
+    "state", "kernels"}``: per-round losses and seconds, the round step's
+    compile time (None on the scan path), the final federated state, and
+    how the kernels ran (compiled / interpret / off)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config (CPU-trainable)")
     ap.add_argument("--rounds", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the weights and the synthetic client data")
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--dp", type=int, default=1)
@@ -118,25 +123,25 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     if args.devices:
+        # a host mesh of forced CPU devices: never the chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.configs import FedConfig, TrainConfig
     from repro.configs.registry import get_arch
-    from repro.core.mesh import (build_fed_round, fed_batch_defs,
-                                 fed_state_defs, init_fed_state)
+    from repro.core.mesh import init_fed_state, jit_fed_round
     from repro.data.synthetic import FederatedLMData
-    from repro.kernels.ops import KernelImpl
+    from repro.kernels.ops import default_kernel_impl
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
-    from repro.models import params as pdefs
     from repro.models.model import Model
-    from repro.sharding.rules import ParallelContext
+
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.smoke if args.smoke else spec.model
@@ -190,83 +195,86 @@ def main(argv=None) -> None:
                     track_gamma=fault is None,
                     fault=fault)
     train = TrainConfig(global_batch=args.global_batch, seq_len=args.seq_len,
-                        rounds=args.rounds, remat_policy="none")
+                        rounds=args.rounds, remat_policy="none",
+                        seed=args.seed)
     model = Model(cfg, tp=args.tp)
-    ctx = ParallelContext(model_axis="model" if args.tp > 1 else None,
-                          tp=args.tp, client_axes=fed.client_axes,
-                          num_clients=fed.num_clients)
 
-    # forcing the kernel selection provider implies constructing the whole
-    # KernelImpl — build_fed_round then also routes the server update and
-    # dense-path EF through the fused kernels, exactly as --use-kernels
-    kernel_impl = (KernelImpl() if args.use_kernels
-                   or args.mesh_sparse_impl == "kernel"
-                   or args.fused_ingest == "kernel" else None)
-    rnd = build_fed_round(model, fed, train, ctx, kernel_impl=kernel_impl)
-    sdefs = fed_state_defs(model, fed)
-    state_specs = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
-    bdefs = fed_batch_defs(model, fed, train)
-    batch_specs = jax.tree.map(lambda d: d.spec, bdefs, is_leaf=pdefs.is_def)
-    # donate the federated state: params/opt-moments/EF errors update in
-    # place instead of being copied every round
-    from repro.core.mesh import mesh_metric_specs
-    step = jax.jit(compat.shard_map(rnd, mesh=mesh,
-                                 in_specs=(state_specs, batch_specs, P()),
-                                 out_specs=(state_specs,
-                                            mesh_metric_specs(fed)),
-                                 check_vma=True),
-                   donate_argnums=(0,))
+    # the compiled kernels on TPU (so `auto` resolves to them there); off
+    # TPU only when a flag forces them — forcing the selection provider or
+    # the ingest implies the whole KernelImpl (fused server update and
+    # dense-path EF too), exactly as --use-kernels
+    kernel_impl = default_kernel_impl(
+        forced=args.use_kernels or args.mesh_sparse_impl == "kernel"
+        or args.fused_ingest == "kernel")
+    # the federated state is donated: params/opt-moments/EF errors update
+    # in place instead of being copied every round
+    step = jit_fed_round(model, fed, train, mesh, kernel_impl=kernel_impl)
     scan_step = None
     if args.scan_rounds and args.scan_rounds > 1:
-        from repro.core.mesh import build_fed_rounds_scan, scan_batch_specs
-        scan_step = jax.jit(compat.shard_map(
-            build_fed_rounds_scan(rnd), mesh=mesh,
-            in_specs=(state_specs, scan_batch_specs(batch_specs), P(None)),
-            out_specs=(state_specs, mesh_metric_specs(fed, scan=True)),
-            check_vma=True), donate_argnums=(0,))
-    state = init_fed_state(model, fed, jax.random.PRNGKey(train.seed))
+        scan_step = jit_fed_round(model, fed, train, mesh,
+                                  kernel_impl=kernel_impl, scan=True)
+    state = init_fed_state(model, fed, jax.random.PRNGKey(train.seed),
+                           mesh=mesh)
     nparams = sum(int(np.prod(l.shape))
                   for l in jax.tree.leaves(state.params))
+    kernels = ("off" if kernel_impl is None else
+               "compiled" if kernel_impl.compiled else "interpret")
     print(f"arch={cfg.name} params={nparams/1e6:.1f}M clients={num_clients} "
-          f"algo={fed.algorithm}/{fed.compressor} mesh={args.dp}x{args.tp}")
+          f"algo={fed.algorithm}/{fed.compressor} mesh={args.dp}x{args.tp} "
+          f"kernels={kernels}")
 
     data = FederatedLMData(num_clients=max(num_clients, 1),
                            vocab_size=cfg.vocab_size, seed=train.seed)
-    t0 = time.time()
+    history = []
+
+    def log(r, loss, seconds, extra=""):
+        history.append({"round": r, "loss": loss, "seconds": seconds})
+        if r % args.log_every == 0 or r == train.rounds - 1:
+            print(f"round {r:4d}  loss {loss:8.4f}  {extra}({seconds:.3f}s)",
+                  flush=True)
+
     if scan_step is not None:
         from repro.core.mesh import stage_mesh_rounds
         r = 0
         while r < train.rounds:
+            t0 = time.perf_counter()
             chunk = min(args.scan_rounds, train.rounds - r)
             batch, seeds = stage_mesh_rounds(data, r, chunk, fed.local_steps,
                                              train.global_batch,
                                              train.seq_len)
             state, met = scan_step(state, batch, seeds)
             losses = np.asarray(met["loss"])  # one sync per chunk
+            per = (time.perf_counter() - t0) / chunk
             for i in range(chunk):
-                rr = r + i
-                if rr % args.log_every == 0 or rr == train.rounds - 1:
-                    print(f"round {rr:4d}  loss {float(losses[i]):8.4f}  "
-                          f"({time.time() - t0:.1f}s)")
+                log(r + i, float(losses[i]), per)
             r += chunk
+        compile_s = None
     else:
-        for r in range(train.rounds):
+        def batch_of(r):
             raw = data.mesh_batch(r, fed.local_steps, train.global_batch,
                                   train.seq_len)
-            batch = {k: jnp.asarray(v) for k, v in raw.items()}
-            state, met = step(state, batch, jnp.int32(r))
-            if r % args.log_every == 0 or r == train.rounds - 1:
-                extra = ""
-                if "survivors" in met:
-                    extra = (f"surv {float(met['survivors']):3.0f}  "
-                             f"rej {float(met['rejected']):3.0f}  ")
-                print(f"round {r:4d}  loss {float(met['loss']):8.4f}  "
-                      f"{extra}({time.time() - t0:.1f}s)")
+            return {k: jnp.asarray(v) for k, v in raw.items()}
+
+        t0 = time.perf_counter()
+        step = step.lower(state, batch_of(0), jnp.int32(0)).compile()
+        compile_s = time.perf_counter() - t0
+        print(f"compiled round step in {compile_s:.1f}s", flush=True)
+        for r in range(train.rounds):
+            t0 = time.perf_counter()
+            state, met = step(state, batch_of(r), jnp.int32(r))
+            loss = float(met["loss"])       # waits for the round to finish
+            extra = ""
+            if "survivors" in met:
+                extra = (f"surv {float(met['survivors']):3.0f}  "
+                         f"rej {float(met['rejected']):3.0f}  ")
+            log(r, loss, time.perf_counter() - t0, extra)
     if args.checkpoint:
         from repro.checkpoint import save_pytree
         save_pytree(args.checkpoint, jax.device_get(state._asdict()),
                     {"arch": cfg.name, "rounds": train.rounds})
         print(f"checkpoint -> {args.checkpoint}")
+    return {"history": history, "compile_s": compile_s, "state": state,
+            "kernels": kernels}
 
 
 if __name__ == "__main__":

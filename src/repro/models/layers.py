@@ -95,8 +95,9 @@ def ffn_apply(p, x, ctx: ParallelContext, act: str = "silu", dtype="bfloat16",
 # ---------------------------------------------------------------------------
 
 
-def embed_defs(vocab_padded: int, d_model: int):
-    return {"table": pdefs.embedding(vocab_padded, d_model, shard="model")}
+def embed_defs(vocab_padded: int, d_model: int, scale: float = 1.0):
+    return {"table": pdefs.embedding(vocab_padded, d_model, shard="model",
+                                     scale=scale)}
 
 
 def embed_lookup(p, tokens, ctx: ParallelContext, dtype="bfloat16"):

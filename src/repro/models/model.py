@@ -40,7 +40,10 @@ class Model:
             "final_norm": pdefs.norm_scale(cfg.d_model),
         }
         if not cfg.tie_embeddings:
-            d["unembed"] = embed_defs(self.vocab_padded, cfg.d_model)
+            # unit-RMS hidden states @ d^-1/2-scaled rows: unit-variance
+            # logits, so the initial loss sits near ln(vocab)
+            d["unembed"] = embed_defs(self.vocab_padded, cfg.d_model,
+                                      scale=cfg.d_model ** -0.5)
         if cfg.mtp is not None:
             desc = stack_mod.LayerDesc("attn", 0)
             d["mtp"] = {

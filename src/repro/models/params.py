@@ -13,6 +13,7 @@ so concrete init, dry-run and distribution can never drift apart.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -64,13 +65,20 @@ def abstract_params(defs, mesh=None):
     return jax.tree.map(mk, defs, is_leaf=is_def)
 
 
+def leaf_key(rng, path):
+    """The init key of the leaf at ``path``: ``rng`` folded with a CRC-32
+    of the path string — stable across processes (Python's ``hash`` of a
+    str is salted per process) and independent of leaf order."""
+    digest = zlib.crc32(jax.tree_util.keystr(path).encode()) & 0x7FFFFFFF
+    return jax.random.fold_in(rng, digest)
+
+
 def init_params(defs, rng):
-    """Concretely initialize a defs tree. Per-leaf keys are derived from the
-    flattened path so inits are order-independent."""
+    """Concretely initialize a defs tree (see :func:`leaf_key`)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(defs, is_leaf=is_def)
     leaves = []
     for path, d in flat:
-        key = jax.random.fold_in(rng, hash(jax.tree_util.keystr(path)) % (2**31))
+        key = leaf_key(rng, path)
         if d.init == "zeros":
             arr = jnp.zeros(d.shape, jnp.dtype(d.dtype))
         elif d.init == "ones":
@@ -112,6 +120,7 @@ def norm_scale(dim: int, *, shard: Optional[str] = None) -> ParamDef:
     return ParamDef((dim,), P(shard), init="ones")
 
 
-def embedding(vocab: int, dim: int, *, shard: Optional[str] = None) -> ParamDef:
+def embedding(vocab: int, dim: int, *, shard: Optional[str] = None,
+              scale: float = 1.0) -> ParamDef:
     # vocab-sharded embedding table
-    return ParamDef((vocab, dim), P(shard, None), scale=1.0)
+    return ParamDef((vocab, dim), P(shard, None), scale=scale)

@@ -37,6 +37,7 @@ def run_forced_devices(code: str, devices: int = 8,
     subprocess prints a JSON summary consumed via
     :func:`forced_devices_json`."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.pathsep.join(
         [SRC, os.path.dirname(__file__)]

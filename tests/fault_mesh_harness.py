@@ -48,7 +48,6 @@ def _run(fed, rounds, edit_errors_row0_at=None):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.configs.base import TrainConfig
     from repro.core.mesh import (build_fed_round, fed_batch_defs,
                                  fed_state_defs, init_fed_state,
@@ -65,7 +64,7 @@ def _run(fed, rounds, edit_errors_row0_at=None):
     ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
     bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                        is_leaf=pdefs.is_def)
-    rnd = jax.jit(compat.shard_map(
+    rnd = jax.jit(jax.shard_map(
         build_fed_round(model, fed, train, ctx), mesh=mesh,
         in_specs=(ssp, bsp, P()), out_specs=(ssp, mesh_metric_specs(fed))))
     state = init_fed_state(model, fed, jax.random.PRNGKey(0))

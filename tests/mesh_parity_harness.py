@@ -141,7 +141,6 @@ def _run_mesh(fed, rounds_targets, kernel_impl):
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.configs.base import TrainConfig
     from repro.core.mesh import (build_fed_round, fed_batch_defs,
                                  fed_state_defs, init_fed_state)
@@ -164,7 +163,7 @@ def _run_mesh(fed, rounds_targets, kernel_impl):
     ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
     bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                        is_leaf=pdefs.is_def)
-    rnd = jax.jit(compat.shard_map(
+    rnd = jax.jit(jax.shard_map(
         build_fed_round(model, fed, train, ctx, kernel_impl=kernel_impl),
         mesh=mesh, in_specs=(ssp, bsp, P()),
         out_specs=(ssp, {"loss": P(), "wire_up_bytes": P()})))
@@ -280,7 +279,6 @@ def jaxpr_payload(compressor: str) -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.configs.base import FedConfig, TrainConfig
     from repro.core.mesh import (build_fed_round, fed_batch_defs,
                                  fed_state_defs, init_fed_state,
@@ -314,7 +312,7 @@ def jaxpr_payload(compressor: str) -> dict:
     ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
     bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                        is_leaf=pdefs.is_def)
-    fn = compat.shard_map(build_fed_round(model, fed, train, ctx),
+    fn = jax.shard_map(build_fed_round(model, fed, train, ctx),
                           mesh=mesh, in_specs=(ssp, bsp, P()),
                           out_specs=(ssp, {"loss": P(),
                                            "wire_up_bytes": P()}))
@@ -325,10 +323,7 @@ def jaxpr_payload(compressor: str) -> dict:
     gathered = []      # (bytes, shape) per all_gather operand
     counts = {"top_k": 0, "argmax": 0}
 
-    try:  # jax >= 0.6 moved the jaxpr types; 0.4.x has them on jax.core
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:  # pragma: no cover
-        ClosedJaxpr, Jaxpr = jax.core.ClosedJaxpr, jax.core.Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(params):
         for v in params.values():
@@ -376,7 +371,6 @@ def jaxpr_payload_hier() -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.configs.base import FedConfig, TrainConfig
     from repro.core.mesh import (build_fed_round, fed_batch_defs,
                                  fed_state_defs, init_fed_state,
@@ -410,7 +404,7 @@ def jaxpr_payload_hier() -> dict:
     ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
     bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                        is_leaf=pdefs.is_def)
-    fn = compat.shard_map(build_fed_round(model, fed, train, ctx),
+    fn = jax.shard_map(build_fed_round(model, fed, train, ctx),
                           mesh=mesh, in_specs=(ssp, bsp, P()),
                           out_specs=(ssp, {"loss": P(),
                                            "wire_up_bytes": P()}))
@@ -420,10 +414,7 @@ def jaxpr_payload_hier() -> dict:
 
     tiers = {"tier1": [], "tier2": []}   # (operand bytes, shape) per gather
 
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:  # pragma: no cover
-        ClosedJaxpr, Jaxpr = jax.core.ClosedJaxpr, jax.core.Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(params):
         for v in params.values():

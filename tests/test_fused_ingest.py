@@ -32,6 +32,7 @@ import jax.flatten_util
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.configs.base import FedConfig
 from repro.core.compressors import block_layout, make_compressor
@@ -313,9 +314,9 @@ def _scatter_add_shapes(jaxpr):
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else (v,)
             for sub in vs:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     shapes.extend(_scatter_add_shapes(sub.jaxpr))
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     shapes.extend(_scatter_add_shapes(sub))
     return shapes
 

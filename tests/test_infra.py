@@ -177,3 +177,32 @@ def test_hlo_analyzer_nested_scans():
     c = jax.jit(f).lower(x, x).compile()
     hc = analyze(c.as_text())
     assert hc.flops == 2 * 32 ** 3 * 15
+
+
+def test_backend_spec_keyed_by_device_kind():
+    """Peaks are looked up by ``device_kind``; a device with no published
+    peaks (the CPU here) raises instead of borrowing another chip's."""
+    from repro.launch.mesh import backend_spec
+    v5e = backend_spec("TPU v5 lite")
+    assert (v5e.peak_flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        backend_spec("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        backend_spec()      # the first local device: the CPU
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the cache sits at the checkout's
+    .jax_cache; with it set, JAX's own reading of it is left alone."""
+    from repro.launch import cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cache.enable_compile_cache()
+        assert path == os.path.join(cache.CHECKOUT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

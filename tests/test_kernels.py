@@ -180,11 +180,11 @@ def test_kernel_impl_topk_select_leaf_matches_compressor():
 
 
 def test_kernel_impl_interpret_resolves_by_backend():
-    """interpret=None resolves like kernels.bitpack: interpreter off-TPU,
-    compiled on TPU; an explicit bool is honored unchanged."""
+    """interpret=None lets the platform decide: the interpreter on the CPU
+    platform only, compiled elsewhere; an explicit bool is honored."""
     ki = KernelImpl()
     assert ki.interpret is None
-    expected = jax.default_backend() != "tpu"
+    expected = jax.default_backend() == "cpu"
     assert ki._interp is expected
     assert KernelImpl(interpret=True)._interp is True
     assert KernelImpl(interpret=False)._interp is False

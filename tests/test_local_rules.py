@@ -239,7 +239,7 @@ def test_ef_tracks_exactly_what_was_sent(fed_kw):
                                     {"compressor": "topkk"},
                                     {"aggregation": "sparse_topk"},
                                     {"local_opt": "adam"},
-                                    {"wire_pack_impl": "triton"},
+                                    {"mesh_sparse_impl": "triton"},
                                     {"eta_l_decay": 0.0},
                                     {"eta_l_decay": 1.5},
                                     {"local_steps_min": -1},

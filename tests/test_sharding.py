@@ -6,31 +6,19 @@ numerically equivalent to the pure-simulation path (same clients, same
 batches, same server math) — the SPMD mapping introduces no drift."""
 import json
 
-import jax
 import pytest
 
 from conftest import run_forced_devices
-
-# Exact TP gradients through shard_map need the vma machinery
-# (jax.shard_map with check_vma); on jax 0.4.x the compat path runs the
-# experimental shard_map with check_rep=False, whose psum transpose is off
-# by tp factors — see repro/compat.py and ParallelContext.tp_copy.
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="exact TP gradients need vma-era jax.shard_map (jax >= 0.7)")
-
 
 def run_sub(code: str, devices: int = 8) -> str:
     return run_forced_devices(code, devices, timeout=600)
 
 
 @pytest.mark.slow
-@requires_vma
 def test_mesh_round_matches_simulation():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.configs.base import ModelConfig, FedConfig, TrainConfig
         from repro.core.rounds import (FedSim, build_fed_round,
                                        init_fed_state, fed_state_defs,
@@ -56,7 +44,7 @@ def test_mesh_round_matches_simulation():
         ssp = jax.tree.map(lambda d: d.spec, sdefs, is_leaf=pdefs.is_def)
         bsp = jax.tree.map(lambda d: d.spec, fed_batch_defs(model, fed, train),
                            is_leaf=pdefs.is_def)
-        rnd = jax.jit(compat.shard_map(build_fed_round(model, fed, train, ctx),
+        rnd = jax.jit(jax.shard_map(build_fed_round(model, fed, train, ctx),
                       mesh=mesh, in_specs=(ssp, bsp, P()),
                       out_specs=(ssp, {"loss": P(), "wire_up_bytes": P()})))
         state = init_fed_state(model, fed, jax.random.PRNGKey(0))
@@ -100,7 +88,6 @@ def test_sparse_aggregation_equals_dense_topk():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.configs.base import ModelConfig, FedConfig, TrainConfig
         from repro.core.rounds import (build_fed_round, init_fed_state,
                                        fed_state_defs, fed_batch_defs)
@@ -133,7 +120,7 @@ def test_sparse_aggregation_equals_dense_topk():
             bsp = jax.tree.map(lambda d: d.spec,
                                fed_batch_defs(model, fed, train),
                                is_leaf=pdefs.is_def)
-            rnd = jax.jit(compat.shard_map(
+            rnd = jax.jit(jax.shard_map(
                 build_fed_round(model, fed, train, ctx), mesh=mesh,
                 in_specs=(ssp, bsp, P()), out_specs=(ssp, {"loss": P(), "wire_up_bytes": P()}),
                 check_vma=True))
@@ -157,7 +144,6 @@ def test_multipod_mesh_and_hierarchical_client():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.configs.base import ModelConfig, FedConfig, TrainConfig
         from repro.core.rounds import (build_fed_round, init_fed_state,
                                        fed_state_defs, fed_batch_defs)
@@ -193,7 +179,7 @@ def test_multipod_mesh_and_hierarchical_client():
             bsp = jax.tree.map(lambda d: d.spec,
                                fed_batch_defs(model, fed, train),
                                is_leaf=pdefs.is_def)
-            rnd = jax.jit(compat.shard_map(
+            rnd = jax.jit(jax.shard_map(
                 build_fed_round(model, fed, train, ctx), mesh=mesh,
                 in_specs=(ssp, bsp, P()), out_specs=(ssp, {"loss": P(), "wire_up_bytes": P()}),
                 check_vma=True))
@@ -213,7 +199,6 @@ def test_seq_sharded_decode_matches_unsharded():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.configs.base import ModelConfig
         from repro.models.model import Model
         from repro.models import params as pdefs
@@ -247,7 +232,7 @@ def test_seq_sharded_decode_matches_unsharded():
         csp = jax.tree.map(lambda d: d.spec, cdefs, is_leaf=pdefs.is_def)
         psp = jax.tree.map(lambda d: P(*[None]*len(d.shape)), model.defs(),
                            is_leaf=pdefs.is_def)
-        step = jax.jit(compat.shard_map(
+        step = jax.jit(jax.shard_map(
             lambda p, t, c, pos: model.decode_step(p, t, c, pos, ctx,
                                                    max_len=max_len),
             mesh=mesh, in_specs=(psp, P(), csp, P()),
@@ -271,7 +256,6 @@ def test_tp_serving_prefill_decode():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.configs.base import ModelConfig
         from repro.models.model import Model, greedy_sample
         from repro.models import params as pdefs
@@ -308,14 +292,14 @@ def test_tp_serving_prefill_decode():
         from repro.launch.steps import remap_defs
         cdefs = remap_defs(cdefs, {"data": None})
         csp = jax.tree.map(lambda d: d.spec, cdefs, is_leaf=pdefs.is_def)
-        prefill = jax.jit(compat.shard_map(
+        prefill = jax.jit(jax.shard_map(
             lambda p, t: model.prefill(p, t, ctx, max_len=max_len),
             mesh=mesh, in_specs=(psp, P()),
             out_specs=(P(None, "model"), csp)))
         def dstep(p, t, c, pos):
             lg, c2 = model.decode_step(p, t, c, pos, ctx, max_len=max_len)
             return greedy_sample(lg, ctx), c2
-        decode = jax.jit(compat.shard_map(
+        decode = jax.jit(jax.shard_map(
             dstep, mesh=mesh, in_specs=(psp, P(), csp, P()),
             out_specs=(P(), csp)))
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 try:
     from hypothesis import given, settings
@@ -303,9 +304,9 @@ def _count_primitive(jaxpr, name: str) -> int:
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else (v,)
             for sub in vs:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     n += _count_primitive(sub.jaxpr, name)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     n += _count_primitive(sub, name)
     return n
 

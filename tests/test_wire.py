@@ -1,5 +1,5 @@
 """repro.comm: wire-format round trips, measured-vs-analytic byte counts,
-bitpack kernels, transport simulation, and FedSim wire mode."""
+word-wise bit packing, transport simulation, and FedSim wire mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,9 +19,8 @@ from repro.comm.wire import pack_uint as wire_pack_uint
 from repro.comm.wire import unpack_uint as wire_unpack_uint
 from repro.configs.base import FedConfig
 from repro.core.rounds import FedSim, mesh_wire_bytes
-from repro.kernels import (pack_bits, pack_bits_ref, pack_uint,
-                           pack_uint_words, unpack_bits, unpack_bits_ref,
-                           unpack_uint, unpack_uint_words)
+from repro.kernels import (pack_bits_ref, pack_uint_words, unpack_bits_ref,
+                           unpack_uint_words)
 from repro.data.synthetic import FederatedClassification
 from repro.models import params as pdefs
 from repro.models.convmixer import MLPConfig, mlp_defs, mlp_loss
@@ -137,27 +136,28 @@ def test_measured_bytes_match_analytic_bits(d):
 
 
 @pytest.mark.parametrize("d", [8, 100, 5000])
-def test_sign_codec_pallas_pack_impl_byte_identical(d):
-    """The Pallas bitpack path produces byte-identical wire buffers."""
-    x = _vec(d + 1, d)
-    jnp_codec = make_sign_codec()
-    pl_codec = make_sign_codec(pack_impl="pallas")
-    b1, b2 = jnp_codec.encode(x), pl_codec.encode(x)
-    assert np.array_equal(np.asarray(b1), np.asarray(b2))
-    assert np.array_equal(np.asarray(pl_codec.decode(b1, d)),
-                          np.asarray(jnp_codec.decode(b1, d)))
+def test_sign_codec_bits_match_packbits_ref(d):
+    """The sign codec's payload after the header and scale is exactly the
+    1-bit oracle's packing of the sign bits (sign(0) := +1)."""
+    x = _vec(d + 1, d).at[0].set(0.0)
+    buf = make_sign_codec().encode(x)
+    bits = (x >= 0).astype(jnp.uint8)
+    pad = (-d) % 8
+    want = pack_bits_ref(jnp.pad(bits, (0, pad)))
+    assert np.array_equal(np.asarray(buf[HEADER_BYTES + 4:]),
+                          np.asarray(want))
 
 
-# -- bitpack kernels ---------------------------------------------------------
+# -- word-wise bit packing ---------------------------------------------------
 
 
-@pytest.mark.parametrize("n,block", [(2048, 2048), (8192, 1024), (4096, 256)])
-def test_bitpack_kernel_matches_refs(n, block):
+@pytest.mark.parametrize("n", [2048, 8192, 4096])
+def test_bitpack_words_match_refs(n):
     bits = jnp.asarray(np.random.default_rng(n).integers(0, 2, n), jnp.uint8)
-    packed = pack_bits(bits, block=block)
+    packed = pack_uint_words(bits, 1)
     assert np.array_equal(np.asarray(packed), np.asarray(pack_bits_ref(bits)))
     assert np.array_equal(np.asarray(packed), np.packbits(np.asarray(bits)))
-    assert np.array_equal(np.asarray(unpack_bits(packed, block=block)),
+    assert np.array_equal(np.asarray(unpack_uint_words(packed, 1, n)),
                           np.asarray(bits))
     assert np.array_equal(np.asarray(unpack_bits_ref(packed)),
                           np.asarray(bits))
@@ -172,54 +172,47 @@ def _naive_pack(vals, nbits):
 
 @given(st.integers(1, 32), st.integers(1, 3000))
 def test_pack_uint_roundtrip_all_widths(nbits, count):
-    """Property: for every nbits in 1..32, jnp word-wise and Pallas paths
-    are byte-identical to the bit-matrix oracle and invert exactly."""
+    """Property: for every nbits in 1..32, the word-wise path is
+    byte-identical to the bit-matrix oracle and inverts exactly."""
     rng = np.random.default_rng(nbits * 10007 + count)
     hi = min(2 ** nbits, 2 ** 32)
     vals = jnp.asarray(
         rng.integers(0, hi, count, dtype=np.uint64).astype(np.uint32))
-    ref = _naive_pack(vals, nbits)
-    for packed in (pack_uint_words(vals, nbits), pack_uint(vals, nbits)):
-        assert packed.dtype == jnp.uint8
-        assert np.array_equal(np.asarray(packed), ref), (nbits, count)
     packed = pack_uint_words(vals, nbits)
-    for un in (unpack_uint_words(packed, nbits, count),
-               unpack_uint(packed, nbits, count)):
-        assert np.array_equal(np.asarray(un), np.asarray(vals)), (nbits,
-                                                                  count)
+    assert packed.dtype == jnp.uint8
+    assert np.array_equal(np.asarray(packed), _naive_pack(vals, nbits)), (
+        nbits, count)
+    un = unpack_uint_words(packed, nbits, count)
+    assert np.array_equal(np.asarray(un), np.asarray(vals)), (nbits, count)
 
 
 @pytest.mark.parametrize("nbits", [1, 3, 8, 11, 17, 32])
-def test_wire_pack_uint_jnp_vs_pallas_parity(nbits):
-    """wire.pack_uint/unpack_uint: both impls byte/value identical."""
+def test_wire_pack_uint_matches_naive(nbits):
+    """wire.pack_uint/unpack_uint: the bit-matrix oracle's bytes, the
+    packed size, and an exact inverse."""
     rng = np.random.default_rng(nbits)
     count = 1357
     hi = min(2 ** nbits, 2 ** 32)
     vals = jnp.asarray(
         rng.integers(0, hi, count, dtype=np.uint64).astype(np.uint32))
-    b_jnp = wire_pack_uint(vals, nbits)
-    b_pl = wire_pack_uint(vals, nbits, "pallas")
-    assert np.array_equal(np.asarray(b_jnp), np.asarray(b_pl))
-    assert b_jnp.size == (count * nbits + 7) // 8
-    u_jnp = wire_unpack_uint(b_jnp, nbits, count)
-    u_pl = wire_unpack_uint(b_jnp, nbits, count, "pallas")
-    assert np.array_equal(np.asarray(u_jnp), np.asarray(vals))
-    assert np.array_equal(np.asarray(u_pl), np.asarray(vals))
+    packed = wire_pack_uint(vals, nbits)
+    assert np.array_equal(np.asarray(packed), _naive_pack(vals, nbits))
+    assert packed.size == (count * nbits + 7) // 8
+    assert np.array_equal(np.asarray(wire_unpack_uint(packed, nbits, count)),
+                          np.asarray(vals))
 
 
-def test_blocktopk_codec_pallas_pack_impl_byte_identical():
-    """blocktopk's 11-bit index stream through the Pallas kernels produces
-    byte-identical wire buffers and decodes."""
+def test_blocktopk_codec_encode_matches_selection():
+    """blocktopk's encode is the compressor's selection packed: the same
+    bytes as encoding ``select(x)``, and it decodes to ``compress(x)``."""
     d = 5000
     x = _vec(17, d)
     jc = make_blocktopk_codec(1 / 8, block=2048)
-    pc = make_blocktopk_codec(1 / 8, block=2048, pack_impl="pallas")
-    b1, b2 = jc.encode(x), pc.encode(x)
+    b1 = jc.encode(x)
+    b2 = jc.encode_from_selection(jc.compressor.select(x), d)
     assert np.array_equal(np.asarray(b1), np.asarray(b2))
-    assert np.array_equal(np.asarray(pc.decode(b1, d)),
-                          np.asarray(jc.decode(b1, d)))
     ref = jc.compressor.compress(x).reshape(-1)
-    assert np.array_equal(np.asarray(pc.decode(b2, d)), np.asarray(ref))
+    assert np.array_equal(np.asarray(jc.decode(b1, d)), np.asarray(ref))
 
 
 # -- transport ---------------------------------------------------------------
