@@ -28,7 +28,8 @@ from repro.core.local import (hetero_step_counts, local_lr, make_local_update,
 from repro.core.sampling import participation_mask
 from repro.core.server_opt import (ServerState, server_ingest_tree,
                                    server_update)
-from repro.core.stages import (mesh_agg_strategy, mesh_uplink,
+from repro.core.stages import (EF_SELECT, LOCAL_TRAIN, SERVER_INGEST, UPLINK,
+                               mesh_agg_strategy, mesh_uplink,
                                resolve_fused_ingest,
                                resolve_mesh_sparse_impl,
                                sparse_topk_leaf_validated, topk_select_tree)
@@ -286,7 +287,10 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
                     ctx: ParallelContext, *, chunk: int = 2048,
                     kernel_impl: Optional[object] = None):
     """Returns fed_round(state, batch, seed) — the per-device SPMD function
-    (wrap in shard_map + jit via launch.train / launch.dryrun)."""
+    (wrap in shard_map + jit via launch.train / launch.dryrun). Its
+    operations carry the layer scopes of
+    :data:`repro.core.stages.LAYER_SCOPES`, each under at most one; the
+    participation mask and the loss's mean stay outside them."""
     # On the mesh, deltas are per-leaf shards (billions of elements for the
     # large archs): global top-k is ill-defined and lax.top_k overflows int32
     # indices, so "topk" means the blockwise TPU kernel semantics here
@@ -416,13 +420,14 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             g = jax.tree.map(lambda x, gg: gg.astype(x.dtype), p, g)
             return l, g
 
-        eta_l = local_lr(fed, state.round)
-        k_all = hetero_step_counts(fed, rng, m_clients)
-        k_i = None if k_all is None else k_all[ctx.client_index()]
-        local, loss_local = run_local_steps(rule, grad_fn, local0, batch,
-                                            eta_l, k_i=k_i)
-        delta = jax.tree.map(lambda a, b_: (a - b_).astype(jnp.float32),
-                             local, local0)
+        with jax.named_scope(LOCAL_TRAIN):
+            eta_l = local_lr(fed, state.round)
+            k_all = hetero_step_counts(fed, rng, m_clients)
+            k_i = None if k_all is None else k_all[ctx.client_index()]
+            local, loss_local = run_local_steps(rule, grad_fn, local0, batch,
+                                                eta_l, k_i=k_i)
+            delta = jax.tree.map(lambda a, b_: (a - b_).astype(jnp.float32),
+                                 local, local0)
 
         # participation (same mask on every device via the shared rng)
         mask = participation_mask(jax.random.fold_in(rng, 1), m_clients, n_part)
@@ -438,18 +443,23 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             n_eff = float(n_part)
         my_mask = mask[ctx.client_index()]
 
-        my_err = jax.tree.map(lambda e: e[0], state.errors)  # local client slice
+        with jax.named_scope(EF_SELECT):
+            # local client slice
+            my_err = jax.tree.map(lambda e: e[0], state.errors)
         st = ServerState(m=state.m, v=state.v, vhat=state.vhat, t=state.round)
 
         def _server_step(agg):
-            # server update (replicated elementwise math on sharded leaves)
-            if kernel_impl is not None and fed.algorithm in (
-                    "fedams", "fedcams", "fedamsgrad"):
-                return kernel_impl.fedams_update_tree(fed, st, params, agg)
-            if fed.shard_server_state and fed.state_shards > 1:
-                return _sharded_server_update(fed, st, params, agg, model,
-                                              ctx)
-            return server_update(fed, st, params, agg)
+            # server update (replicated elementwise math on sharded leaves;
+            # the ZeRO form's all-gather of the new params is the server's)
+            with jax.named_scope(SERVER_INGEST):
+                if kernel_impl is not None and fed.algorithm in (
+                        "fedams", "fedcams", "fedamsgrad"):
+                    return kernel_impl.fedams_update_tree(fed, st, params,
+                                                          agg)
+                if fed.shard_server_state and fed.state_shards > 1:
+                    return _sharded_server_update(fed, st, params, agg,
+                                                  model, ctx)
+                return server_update(fed, st, params, agg)
 
         rejected = jnp.zeros(())
         if fused != "off":
@@ -458,13 +468,15 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             # Selections (identical collective + payload to
             # sparse_topk_leaf), and run scatter-mean + FedAMS update in a
             # single read-modify-write over the optimizer state — no dense
-            # mean delta is materialized (bit-identical at fp32 state)
-            if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
-                sels, new_err = kernel_impl.topk_select_tree(
-                    comp.ratio, delta, my_err, my_mask)
-            else:
-                sels, new_err = topk_select_tree(comp, delta, my_err,
-                                                 my_mask)
+            # mean delta is materialized (bit-identical at fp32 state); the
+            # ingest scopes each leaf's gathers and its pass apart
+            with jax.named_scope(EF_SELECT):
+                if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
+                    sels, new_err = kernel_impl.topk_select_tree(
+                        comp.ratio, delta, my_err, my_mask)
+                else:
+                    sels, new_err = topk_select_tree(comp, delta, my_err,
+                                                     my_mask)
             gather = lambda a: ctx.all_gather_clients(a[None], axis=0)
             if fused == "kernel":
                 new_params, new_st = kernel_impl.fedams_ingest_tree(
@@ -482,19 +494,22 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             # aggregate over alive ∧ valid. A rejected client is NACKed:
             # its EF row rolls back to the stale pre-round value, the
             # same drop semantics core/error_feedback.py documents.
-            if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
-                sels, new_err = kernel_impl.topk_select_tree(
-                    comp.ratio, delta, my_err, my_mask)
-            else:
-                sels, new_err = topk_select_tree(comp, delta, my_err,
-                                                 my_mask)
-            plan = mesh_corruption_plan(fcfg, rng, m_clients)
-            ci = ctx.client_index()
-            myplan = jax.tree.map(lambda a: a[ci][None], plan)
+            with jax.named_scope(EF_SELECT):
+                if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
+                    sels, new_err = kernel_impl.topk_select_tree(
+                        comp.ratio, delta, my_err, my_mask)
+                else:
+                    sels, new_err = topk_select_tree(comp, delta, my_err,
+                                                     my_mask)
+            with jax.named_scope(UPLINK):
+                plan = mesh_corruption_plan(fcfg, rng, m_clients)
+                ci = ctx.client_index()
+                myplan = jax.tree.map(lambda a: a[ci][None], plan)
 
             def leaf_fault(s, lf):
-                cv, cidx = corrupt_selection(s.vals[None], s.idx[None],
-                                             myplan, fcfg.corrupt_mode)
+                with jax.named_scope(UPLINK):     # damage in transit
+                    cv, cidx = corrupt_selection(s.vals[None], s.idx[None],
+                                                 myplan, fcfg.corrupt_mode)
                 bs, nb = block_layout(lf.size, sparse_block)
                 return sparse_topk_leaf_validated(
                     Selection(vals=cv[0], idx=cidx[0]), lf, mask, ctx,
@@ -510,20 +525,22 @@ def build_fed_round(model, fed: FedConfig, train: TrainConfig,
             # a client survives only if EVERY leaf validated — one damaged
             # leaf NACKs the whole client update (EF rolls back, so the
             # full residual repays on the next clean round)
-            my_valid = valid_leaves[0]
-            for v in valid_leaves[1:]:
-                my_valid = my_valid * v
-            new_err = jax.tree.map(
-                lambda ne, eo: jnp.where(my_valid > 0, ne, eo),
-                new_err, my_err)
+            with jax.named_scope(EF_SELECT):
+                my_valid = valid_leaves[0]
+                for v in valid_leaves[1:]:
+                    my_valid = my_valid * v
+                new_err = jax.tree.map(
+                    lambda ne, eo: jnp.where(my_valid > 0, ne, eo),
+                    new_err, my_err)
             new_params, new_st = _server_step(agg)
         else:
             agg, new_err = mesh_uplink(fed, comp, ctx, kernel_impl, rng,
                                        delta, my_err, my_mask, n_eff)
             new_params, new_st = _server_step(agg)
 
-        errors = jax.tree.map(lambda e, ne: e.at[0].set(ne),
-                              state.errors, new_err)
+        with jax.named_scope(EF_SELECT):
+            errors = jax.tree.map(lambda e, ne: e.at[0].set(ne),
+                                  state.errors, new_err)
         loss = ctx.pmean_clients(loss_local)
         if hierarchical:
             loss = ctx.pmean_data(loss)

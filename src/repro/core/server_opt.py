@@ -333,9 +333,12 @@ def server_ingest_tree(fed: FedConfig, st: ServerState, params, sels, n_div,
     gathered (n, nb·k) client-major stack (the client-axis all_gather —
     the identical collective ``stages.sparse_topk_leaf`` runs, so the wire
     payload is unchanged). Returns ``(new_params, new_state)`` like
-    ``KernelImpl.fedams_update_tree``.
+    ``KernelImpl.fedams_update_tree``. Each leaf's gathers run under the
+    ``uplink`` scope and its ingest under ``server_ingest``, apart, as the
+    mesh round's layer scopes never nest.
     """
     from repro.core.compressors import Selection, block_layout
+    from repro.core.stages import SERVER_INGEST, UPLINK
     is_sel = lambda s: isinstance(s, Selection)
     flat_p, tdef = jax.tree_util.tree_flatten(params)
     flat_m = jax.tree_util.tree_leaves(st.m)
@@ -352,15 +355,17 @@ def server_ingest_tree(fed: FedConfig, st: ServerState, params, sels, n_div,
         pads = lambda a: (a if _is_quant(a) else
                           (jnp.pad(a.reshape(-1), (0, pad)) if pad
                            else a.reshape(-1)))
-        x2, m2, v2, vh2 = server_ingest_leaf(
-            fed, padf(x), padf(m), pads(v), pads(vh),
-            gather(sel.vals), gather(sel.idx), n_div,
-            block=bs, impl=impl, interpret=interpret)
-        n = x.size
-        xs.append(x2[:n].reshape(x.shape).astype(x.dtype))
-        ms.append(m2[:n].reshape(x.shape))
-        vs.append(v2 if _is_quant(v2) else v2[:n].reshape(x.shape))
-        vhs.append(vh2 if _is_quant(vh2) else vh2[:n].reshape(x.shape))
+        with jax.named_scope(UPLINK):
+            g_vals, g_idx = gather(sel.vals), gather(sel.idx)
+        with jax.named_scope(SERVER_INGEST):
+            x2, m2, v2, vh2 = server_ingest_leaf(
+                fed, padf(x), padf(m), pads(v), pads(vh), g_vals, g_idx,
+                n_div, block=bs, impl=impl, interpret=interpret)
+            n = x.size
+            xs.append(x2[:n].reshape(x.shape).astype(x.dtype))
+            ms.append(m2[:n].reshape(x.shape))
+            vs.append(v2 if _is_quant(v2) else v2[:n].reshape(x.shape))
+            vhs.append(vh2 if _is_quant(vh2) else vh2[:n].reshape(x.shape))
     unf = lambda ls: jax.tree_util.tree_unflatten(tdef, ls)
     return unf(xs), ServerState(m=unf(ms), v=unf(vs), vhat=unf(vhs),
                                 t=st.t + 1)

@@ -39,6 +39,11 @@ Mesh-side stages (per-device pytree shards + client-axis collectives):
 * :func:`packed_sign_leaf`  — 1-bit/coordinate packed-sign all_gather.
 * :func:`mesh_uplink`       — the full uplink: aggregation-strategy
   selection + masked EF + delta-dtype narrowing.
+
+The mesh stages name the round's layers with :data:`LAYER_SCOPES`, as
+``jax.named_scope`` s that never nest, so every compiled instruction of a
+round sits under at most one of them and a profiler trace can be summed
+by layer.
 """
 from __future__ import annotations
 
@@ -51,6 +56,14 @@ from repro.configs.base import FedConfig
 from repro.core.compressors import Compressor, Selection
 from repro.core.error_feedback import ef_compress, ef_compress_masked
 from repro.sharding.rules import ParallelContext
+
+#: The mesh round's four layers as ``jax.named_scope`` names: the clients'
+#: local steps and the delta they leave, client EF and selection (with the
+#: EF row write), the client-axis collective, and what the server does
+#: with what arrived (scatter-mean and the adaptive step). The scopes
+#: never nest, since a trace reader matches them by substring.
+LAYER_SCOPES = ("local_train", "ef_select", "uplink", "server_ingest")
+LOCAL_TRAIN, EF_SELECT, UPLINK, SERVER_INGEST = LAYER_SCOPES
 
 
 # ===========================================================================
@@ -243,10 +256,12 @@ def agg_dense(hat_tree, my_mask, n_eff, ctx: ParallelContext,
     narrows the collective payload (bf16 halves client-axis bytes; the
     caller keeps error feedback exact by tracking the narrowed value)."""
     wd = jnp.dtype(wire_dtype)
-    contrib = jax.tree.map(
-        lambda h: jnp.where(my_mask > 0, h, 0.0).astype(wd), hat_tree)
-    return jax.tree.map(
-        lambda c: ctx.psum_clients(c).astype(jnp.float32) / n_eff, contrib)
+    with jax.named_scope(UPLINK):
+        contrib = jax.tree.map(
+            lambda h: jnp.where(my_mask > 0, h, 0.0).astype(wd), hat_tree)
+        summed = jax.tree.map(ctx.psum_clients, contrib)
+    with jax.named_scope(SERVER_INGEST):
+        return jax.tree.map(lambda c: c.astype(jnp.float32) / n_eff, summed)
 
 
 def mesh_agg_strategy(fed: FedConfig) -> str:
@@ -384,13 +399,15 @@ def sparse_topk_leaf(sel: Selection, leaf, n_eff, ctx: ParallelContext):
     this path. ``leaf`` supplies the output shape; padded-tail indices
     (``idx >= leaf.size``) are dropped by the scatter."""
     d = leaf.size
-    g_vals = ctx.all_gather_clients(sel.vals[None], axis=0).reshape(-1)
-    g_idx = ctx.all_gather_clients(sel.idx[None], axis=0).reshape(-1)
-    # NB: fresh zeros (replicated vma) — zeros_like(varying) would taint the
-    # aggregate as client-varying.
-    zeros = jnp.zeros(d, jnp.float32)
-    agg = zeros.at[g_idx].add(g_vals) / n_eff
-    return agg.reshape(leaf.shape)
+    with jax.named_scope(UPLINK):
+        g_vals = ctx.all_gather_clients(sel.vals[None], axis=0).reshape(-1)
+        g_idx = ctx.all_gather_clients(sel.idx[None], axis=0).reshape(-1)
+    with jax.named_scope(SERVER_INGEST):
+        # NB: fresh zeros (replicated vma) — zeros_like(varying) would
+        # taint the aggregate as client-varying.
+        zeros = jnp.zeros(d, jnp.float32)
+        agg = zeros.at[g_idx].add(g_vals) / n_eff
+        return agg.reshape(leaf.shape)
 
 
 def sparse_topk_leaf_validated(sel: Selection, leaf, mask,
@@ -411,16 +428,18 @@ def sparse_topk_leaf_validated(sel: Selection, leaf, mask,
     count of delivered-but-rejected clients for this leaf."""
     d = leaf.size
     from repro.comm.faults import validate_selection
-    g_vals = ctx.all_gather_clients(sel.vals[None], axis=0)   # (m, k)
-    g_idx = ctx.all_gather_clients(sel.idx[None], axis=0)     # (m, k)
-    vvals, valid = validate_selection(g_vals, g_idx, domain, max_norm)
-    surv = mask * valid
-    contrib = jnp.where(surv[:, None] > 0, vvals, 0.0)
-    zeros = jnp.zeros(d, jnp.float32)
-    agg = zeros.at[g_idx.reshape(-1)].add(contrib.reshape(-1)) \
-        / jnp.maximum(jnp.sum(surv), 1.0)
-    rejected = jnp.sum(mask * (1.0 - valid))
-    return agg.reshape(leaf.shape), valid[ctx.client_index()], rejected
+    with jax.named_scope(UPLINK):
+        g_vals = ctx.all_gather_clients(sel.vals[None], axis=0)   # (m, k)
+        g_idx = ctx.all_gather_clients(sel.idx[None], axis=0)     # (m, k)
+    with jax.named_scope(SERVER_INGEST):
+        vvals, valid = validate_selection(g_vals, g_idx, domain, max_norm)
+        surv = mask * valid
+        contrib = jnp.where(surv[:, None] > 0, vvals, 0.0)
+        zeros = jnp.zeros(d, jnp.float32)
+        agg = zeros.at[g_idx.reshape(-1)].add(contrib.reshape(-1)) \
+            / jnp.maximum(jnp.sum(surv), 1.0)
+        rejected = jnp.sum(mask * (1.0 - valid))
+        return agg.reshape(leaf.shape), valid[ctx.client_index()], rejected
 
 
 def sparse_topk_hier_leaf(sel: Selection, leaf, n_eff,
@@ -437,30 +456,40 @@ def sparse_topk_hier_leaf(sel: Selection, leaf, n_eff,
     :func:`server_aggregate_sparse_grouped` (within-group member-order
     scatter, then the partial-stack reduce)."""
     d = leaf.size
-    g_vals = ctx.all_gather_members(sel.vals[None], axis=0).reshape(-1)
-    g_idx = ctx.all_gather_members(sel.idx[None], axis=0).reshape(-1)
-    # fresh zeros (replicated vma), exactly like sparse_topk_leaf
-    partial = jnp.zeros(d, jnp.float32).at[g_idx].add(g_vals)
-    partials = ctx.all_gather_group_partials(partial[None], axis=0)  # (g, d)
-    agg = jnp.sum(partials, axis=0) / n_eff
-    return agg.reshape(leaf.shape)
+    with jax.named_scope(UPLINK):
+        g_vals = ctx.all_gather_members(sel.vals[None], axis=0).reshape(-1)
+        g_idx = ctx.all_gather_members(sel.idx[None], axis=0).reshape(-1)
+    with jax.named_scope(SERVER_INGEST):
+        # fresh zeros (replicated vma), exactly like sparse_topk_leaf
+        partial = jnp.zeros(d, jnp.float32).at[g_idx].add(g_vals)
+    with jax.named_scope(UPLINK):
+        partials = ctx.all_gather_group_partials(partial[None],
+                                                 axis=0)  # (g, d)
+    with jax.named_scope(SERVER_INGEST):
+        agg = jnp.sum(partials, axis=0) / n_eff
+        return agg.reshape(leaf.shape)
 
 
 def packed_sign_leaf(tot, my_mask, n_eff, ctx: ParallelContext):
     """Beyond-paper: scaled-sign with the sign bits packed 8->1 in uint8 for
     the client-axis all_gather (1 bit/coordinate on the wire)."""
-    flat = tot.reshape(-1)
-    d = flat.size
-    scale = jnp.mean(jnp.abs(flat)) * (my_mask > 0)
-    bits = jnp.packbits((flat >= 0).astype(jnp.uint8))
-    g_bits = ctx.all_gather_clients(bits[None], axis=0)      # (m, d/8)
-    g_scale = ctx.all_gather_clients(scale[None], axis=0)    # (m,)
-    signs = jnp.unpackbits(g_bits, axis=1)[:, :d].astype(jnp.float32) * 2.0 - 1.0
-    agg = (g_scale[:, None] * signs).sum(0) / n_eff
-    # sign(0) := +1 to match the packed bits (error feedback must track the
-    # value the wire actually carried)
-    hat = jnp.mean(jnp.abs(flat)) * jnp.where(flat >= 0, 1.0, -1.0)
-    return agg.reshape(tot.shape), hat.reshape(tot.shape)
+    with jax.named_scope(EF_SELECT):
+        flat = tot.reshape(-1)
+        d = flat.size
+        scale = jnp.mean(jnp.abs(flat)) * (my_mask > 0)
+        bits = jnp.packbits((flat >= 0).astype(jnp.uint8))
+        # sign(0) := +1 to match the packed bits (error feedback must track
+        # the value the wire actually carried)
+        hat = (jnp.mean(jnp.abs(flat))
+               * jnp.where(flat >= 0, 1.0, -1.0)).reshape(tot.shape)
+    with jax.named_scope(UPLINK):
+        g_bits = ctx.all_gather_clients(bits[None], axis=0)      # (m, d/8)
+        g_scale = ctx.all_gather_clients(scale[None], axis=0)    # (m,)
+    with jax.named_scope(SERVER_INGEST):
+        signs = (jnp.unpackbits(g_bits, axis=1)[:, :d].astype(jnp.float32)
+                 * 2.0 - 1.0)
+        agg = (g_scale[:, None] * signs).sum(0) / n_eff
+        return agg.reshape(tot.shape), hat
 
 
 def _split_pairs(pairs):
@@ -493,20 +522,24 @@ def mesh_uplink(fed: FedConfig, comp: Optional[Compressor],
 
     strategy = mesh_agg_strategy(fed)
     if strategy == "packed_sign":
-        tot = jax.tree.map(lambda dd, ee: dd + ee, delta, my_err)
+        with jax.named_scope(EF_SELECT):
+            tot = jax.tree.map(lambda dd, ee: dd + ee, delta, my_err)
         agg, hat = _split_pairs(jax.tree.map(
             lambda t: packed_sign_leaf(t, my_mask, n_eff, ctx), tot))
-        new_err = jax.tree.map(
-            lambda t, h, eo: jnp.where(my_mask > 0, t - h, eo),
-            tot, hat, my_err)
+        with jax.named_scope(EF_SELECT):
+            new_err = jax.tree.map(
+                lambda t, h, eo: jnp.where(my_mask > 0, t - h, eo),
+                tot, hat, my_err)
         return agg, new_err
 
     if strategy in ("sparse_topk", "sparse_topk_hier"):
-        if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
-            sels, new_err = kernel_impl.topk_select_tree(
-                comp.ratio, delta, my_err, my_mask)
-        else:
-            sels, new_err = topk_select_tree(comp, delta, my_err, my_mask)
+        with jax.named_scope(EF_SELECT):
+            if resolve_mesh_sparse_impl(fed, kernel_impl) == "kernel":
+                sels, new_err = kernel_impl.topk_select_tree(
+                    comp.ratio, delta, my_err, my_mask)
+            else:
+                sels, new_err = topk_select_tree(comp, delta, my_err,
+                                                 my_mask)
         # selection and EF are identical across tiers — only the collective
         # topology differs (flat gather vs member-gather + group partials)
         leaf_fn = (sparse_topk_hier_leaf if strategy == "sparse_topk_hier"
@@ -516,19 +549,20 @@ def mesh_uplink(fed: FedConfig, comp: Optional[Compressor],
             sels, delta, is_leaf=_is_selection)
         return agg, new_err
 
-    if kernel_impl is not None:
-        hat, new_err = kernel_impl.ef_compress_tree(comp, delta, my_err,
-                                                    my_mask)
-    else:
-        hat, new_err = ef_compress_masked(comp, delta, my_err, my_mask,
-                                          jax.random.fold_in(rng, 2))
-    if fed.delta_dtype != "float32":
-        # error feedback must track the value actually sent
-        wd = jnp.dtype(fed.delta_dtype)
-        hat_tx = jax.tree.map(
-            lambda h: h.astype(wd).astype(jnp.float32), hat)
-        new_err = jax.tree.map(
-            lambda d, e, h: jnp.where(my_mask > 0, d + e - h, e),
-            delta, my_err, hat_tx)
-        hat = hat_tx
+    with jax.named_scope(EF_SELECT):
+        if kernel_impl is not None:
+            hat, new_err = kernel_impl.ef_compress_tree(comp, delta, my_err,
+                                                        my_mask)
+        else:
+            hat, new_err = ef_compress_masked(comp, delta, my_err, my_mask,
+                                              jax.random.fold_in(rng, 2))
+        if fed.delta_dtype != "float32":
+            # error feedback must track the value actually sent
+            wd = jnp.dtype(fed.delta_dtype)
+            hat_tx = jax.tree.map(
+                lambda h: h.astype(wd).astype(jnp.float32), hat)
+            new_err = jax.tree.map(
+                lambda d, e, h: jnp.where(my_mask > 0, d + e - h, e),
+                delta, my_err, hat_tx)
+            hat = hat_tx
     return agg_dense(hat, my_mask, n_eff, ctx, fed.delta_dtype), new_err

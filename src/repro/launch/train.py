@@ -8,10 +8,19 @@ configs train end-to-end on CPU). Examples:
         --rounds 20 --algorithm fedcams --compressor topk
     PYTHONPATH=src python -m repro.launch.train --arch xlstm-350m --smoke \
         --dp 4 --tp 2 --devices 8 --rounds 10
+
+Each round (or scanned chunk of rounds) is a profiler step ``round``
+holding the host spans ``stage`` (its batch made and put on the device),
+``dispatch`` (the jitted call) and ``sync`` (its loss read back); the
+round's device operations carry the layer scopes of
+``repro.core.stages.LAYER_SCOPES``. ``--trace-dir DIR`` writes the
+profiler's trace of steps 1..``--trace-rounds`` (after compilation) into
+``DIR``.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 
@@ -120,7 +129,15 @@ def main(argv=None) -> dict:
                          "(0/1 = one jitted call per round)")
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--log-every", type=int, default=1)
+    ap.add_argument("--trace-dir", default="",
+                    help="write a profiler trace (jax.profiler, host spans "
+                         "and device operations) of steps 1..--trace-rounds "
+                         "into this directory")
+    ap.add_argument("--trace-rounds", type=int, default=3,
+                    help="how many steps --trace-dir traces")
     args = ap.parse_args(argv)
+    if args.trace_rounds < 1:
+        ap.error("--trace-rounds must be at least 1")
 
     if args.devices:
         # a host mesh of forced CPU devices: never the chip
@@ -226,6 +243,23 @@ def main(argv=None) -> dict:
     data = FederatedLMData(num_clients=max(num_clients, 1),
                            vocab_size=cfg.vocab_size, seed=train.seed)
     history = []
+    span = jax.profiler.TraceAnnotation
+    window = contextlib.ExitStack()
+
+    @contextlib.contextmanager
+    def round_step(i, r):
+        """Loop step ``i`` as the profiler step ``round`` numbered ``r``.
+        The profiler starts before step 1 and stops after step
+        ``--trace-rounds``."""
+        if args.trace_dir and i == 1:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0    # host spans need no Python trace
+            window.enter_context(jax.profiler.trace(args.trace_dir,
+                                                    profiler_options=opts))
+        elif i == args.trace_rounds + 1:
+            window.close()
+        with jax.profiler.StepTraceAnnotation("round", step_num=r):
+            yield
 
     def log(r, loss, seconds, extra=""):
         history.append({"round": r, "loss": loss, "seconds": seconds})
@@ -233,41 +267,51 @@ def main(argv=None) -> dict:
             print(f"round {r:4d}  loss {loss:8.4f}  {extra}({seconds:.3f}s)",
                   flush=True)
 
-    if scan_step is not None:
-        from repro.core.mesh import stage_mesh_rounds
-        r = 0
-        while r < train.rounds:
-            t0 = time.perf_counter()
-            chunk = min(args.scan_rounds, train.rounds - r)
-            batch, seeds = stage_mesh_rounds(data, r, chunk, fed.local_steps,
-                                             train.global_batch,
-                                             train.seq_len)
-            state, met = scan_step(state, batch, seeds)
-            losses = np.asarray(met["loss"])  # one sync per chunk
-            per = (time.perf_counter() - t0) / chunk
-            for i in range(chunk):
-                log(r + i, float(losses[i]), per)
-            r += chunk
-        compile_s = None
-    else:
-        def batch_of(r):
-            raw = data.mesh_batch(r, fed.local_steps, train.global_batch,
-                                  train.seq_len)
-            return {k: jnp.asarray(v) for k, v in raw.items()}
+    with window:                  # a trace that outlives the rounds stops
+        if scan_step is not None:
+            from repro.core.mesh import stage_mesh_rounds
+            r = i = 0
+            while r < train.rounds:
+                chunk = min(args.scan_rounds, train.rounds - r)
+                with round_step(i, r):
+                    t0 = time.perf_counter()
+                    with span("stage"):
+                        batch, seeds = stage_mesh_rounds(
+                            data, r, chunk, fed.local_steps,
+                            train.global_batch, train.seq_len)
+                    with span("dispatch"):
+                        state, met = scan_step(state, batch, seeds)
+                    with span("sync"):
+                        losses = np.asarray(met["loss"])  # one sync a chunk
+                per = (time.perf_counter() - t0) / chunk
+                for j in range(chunk):
+                    log(r + j, float(losses[j]), per)
+                r, i = r + chunk, i + 1
+            compile_s = None
+        else:
+            def batch_of(r):
+                raw = data.mesh_batch(r, fed.local_steps, train.global_batch,
+                                      train.seq_len)
+                return {k: jnp.asarray(v) for k, v in raw.items()}
 
-        t0 = time.perf_counter()
-        step = step.lower(state, batch_of(0), jnp.int32(0)).compile()
-        compile_s = time.perf_counter() - t0
-        print(f"compiled round step in {compile_s:.1f}s", flush=True)
-        for r in range(train.rounds):
             t0 = time.perf_counter()
-            state, met = step(state, batch_of(r), jnp.int32(r))
-            loss = float(met["loss"])       # waits for the round to finish
-            extra = ""
-            if "survivors" in met:
-                extra = (f"surv {float(met['survivors']):3.0f}  "
-                         f"rej {float(met['rejected']):3.0f}  ")
-            log(r, loss, time.perf_counter() - t0, extra)
+            step = step.lower(state, batch_of(0), jnp.int32(0)).compile()
+            compile_s = time.perf_counter() - t0
+            print(f"compiled round step in {compile_s:.1f}s", flush=True)
+            for r in range(train.rounds):
+                with round_step(r, r):
+                    t0 = time.perf_counter()
+                    with span("stage"):
+                        batch = batch_of(r)
+                    with span("dispatch"):
+                        state, met = step(state, batch, jnp.int32(r))
+                    with span("sync"):
+                        loss = float(met["loss"])  # waits for the round
+                extra = ""
+                if "survivors" in met:
+                    extra = (f"surv {float(met['survivors']):3.0f}  "
+                             f"rej {float(met['rejected']):3.0f}  ")
+                log(r, loss, time.perf_counter() - t0, extra)
     if args.checkpoint:
         from repro.checkpoint import save_pytree
         save_pytree(args.checkpoint, jax.device_get(state._asdict()),

@@ -61,3 +61,30 @@ def test_train_main_kernel_round_equals_jnp_round():
     assert a["history"][0]["loss"] == b["history"][0]["loss"]
     for x, y in zip(_leaves(a["state"]), _leaves(b["state"])):
         assert np.array_equal(x, y)
+
+
+@pytest.mark.parametrize("rounds,extra,traced", [
+    ("3", ["--trace-rounds", "2"], 2),
+    ("4", ["--scan-rounds", "2", "--trace-rounds", "1"], 1)],
+    ids=["per-round", "scan"])
+def test_train_main_traces_its_rounds_host_spans(tmp_path, rounds, extra,
+                                                 traced):
+    """``--trace-dir`` writes a profiler trace of steps 1..--trace-rounds
+    (rounds, or scanned chunks of rounds): each traced step is one
+    ``round`` holding one ``stage``, one ``dispatch`` and one ``sync``
+    span, the names the benchmark's trace reduction reads."""
+    import collections
+    import glob
+    import os
+    from repro.launch.train import main
+    main(ARGS + PROVIDERS["jnp"] + ["--rounds", rounds,
+                                    "--trace-dir", str(tmp_path)] + extra)
+    files = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1
+    names = collections.Counter(
+        e.name for plane in jax.profiler.ProfileData.from_file(files[0]).planes
+        if not plane.name.startswith("/device:")
+        for line in plane.lines for e in line.events)
+    spans = ("round", "stage", "dispatch", "sync")
+    assert {n: names[n] for n in spans} == dict.fromkeys(spans, traced)
