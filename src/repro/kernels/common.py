@@ -5,8 +5,13 @@ Tiling: a flat vector of ``nb`` selection blocks is viewed as the
 ``(nb, block)`` matrix (a free reshape) and a grid step takes
 :func:`row_grid` whole rows, so each block is one sublane row of the
 tile and per-block reductions run along the lanes. ``block`` is a multiple
-of 128; a tile of fewer than 8 rows only occurs when it spans the whole
-matrix, which keeps every block shape aligned to the TPU's (8, 128) tiling.
+of 128; a tile whose rows are not a multiple of 8 only occurs when it
+spans the whole matrix, which keeps every block shape aligned to the TPU's
+(8, 128) tiling. Most kernels take :data:`ROWS` rows a step; the top-k
+kernels choose their own rows from the block size
+(:func:`repro.kernels.topk_ef.tile_rows`): their per-block selection is a
+serial chain of cross-lane reductions, and only a taller tile gives it
+independent sublane groups to overlap.
 """
 from __future__ import annotations
 

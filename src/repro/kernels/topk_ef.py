@@ -8,6 +8,16 @@ inside each block, and writes both the compressed block and the residual
 error in the same pass — the error-feedback update is fused, so the delta is
 read exactly once.
 
+Tile height: these kernels take :func:`tile_rows` blocks a grid step, as
+many as a fixed VMEM budget holds at the block size, not the package's
+8-row :data:`~repro.kernels.common.ROWS`. The selection is a serial chain
+of per-block cross-lane reductions (one count per threshold bit and tie
+bit, a min and a sum per extracted entry); with 8 rows a step holds one
+sublane group, so each reduction's latency is paid alone, about a hundred
+times a step. A taller tile issues the same chain for many independent
+sublane groups at once, which hides that latency; the bytes moved are the
+same either way.
+
 Two output layouts share the selection logic:
 
 * :func:`topk_ef` — dense (hat, new_err), the historical contract used by
@@ -39,9 +49,20 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_arg, out_struct, row_grid
+from repro.kernels.common import ROWS, interpret_arg, out_struct, row_grid
 
 DEFAULT_BLOCK = 2048
+
+#: VMEM bytes of one (rows, block) f32 tile of a top-k grid step.
+TILE_BYTES = 64 * 2048 * 4
+
+
+def tile_rows(block: int) -> int:
+    """Selection blocks per grid step of the top-k kernels: the largest
+    multiple of :data:`ROWS` whose ``(rows, block)`` f32 tile fits
+    :data:`TILE_BYTES` (at least ``ROWS``). :func:`row_grid` caps it at
+    the block count, so a small leaf is one step of all its blocks."""
+    return max(ROWS, TILE_BYTES // (4 * block) // ROWS * ROWS)
 
 
 def _count(mask):
@@ -109,10 +130,12 @@ def _topk_ef_sparse_kernel(x_ref, e_ref, vals_ref, idx_ref, err_ref, *,
 
 
 def _tiles(x, block):
+    """The ``(nb, block)`` view's grid of :func:`tile_rows` rows a step
+    (the selection's reduction chain is latency-bound, not byte-bound)."""
     n = x.shape[0]
     assert x.ndim == 1 and n % block == 0, (n, block)
     nb = n // block
-    grid, r = row_grid(nb)
+    grid, r = row_grid(nb, tile_rows(block))
     return nb, grid, r, pl.BlockSpec((r, block), lambda i: (i, 0))
 
 

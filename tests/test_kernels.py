@@ -15,10 +15,17 @@ from repro.kernels import ref
 from repro.kernels.fedams_update import fedams_update
 from repro.kernels.ops import KernelImpl
 from repro.kernels.sign_ef import sign_ef
-from repro.kernels.topk_ef import topk_ef, topk_ef_sparse
+from repro.kernels.topk_ef import tile_rows, topk_ef, topk_ef_sparse
 
 settings.register_profile("ci", max_examples=15, deadline=None)
 settings.load_profile("ci")
+
+
+def _steps_n(block):
+    """Elements of a top-k matrix of two full grid steps of
+    :func:`tile_rows` blocks and a partial third (the row count differs
+    per step, so the global ``idx`` offset is exercised)."""
+    return (2 * tile_rows(block) + 3) * block
 
 
 def _pair(seed, n):
@@ -28,7 +35,8 @@ def _pair(seed, n):
 
 
 @pytest.mark.parametrize("n,block,k", [(256, 64, 4), (1024, 128, 16),
-                                       (4096, 2048, 32), (8192, 1024, 1)])
+                                       (4096, 2048, 32), (8192, 1024, 1),
+                                       (_steps_n(128), 128, 2)])
 def test_topk_ef_matches_ref(n, block, k):
     x, e = _pair(0, n)
     h1, e1 = topk_ef(x, e, k=k, block=block)
@@ -120,12 +128,15 @@ def test_fedams_kernel_vhat_monotone(seed):
         vh = vh2
 
 
-def test_topk_ef_keeps_exactly_k_on_ties():
+@pytest.mark.parametrize("n,block", [(4096, 2048), (_steps_n(128), 128)])
+def test_topk_ef_keeps_exactly_k_on_ties(n, block):
     """Ties regression: a tile of equal |values| must keep EXACTLY k
     entries (lowest indices first, lax.top_k order) — the old threshold
     formulation (|x| >= kth) kept every tied entry, breaking the wire
-    format's fixed (vals, idx) sizes and the bits_per_message accounting."""
-    n, block, k = 4096, 2048, 7
+    format's fixed (vals, idx) sizes and the bits_per_message accounting.
+    Both kernels, over one grid step and over several with a partial
+    last one."""
+    k = 7
     x = jnp.ones(n, jnp.float32)
     e = jnp.zeros(n, jnp.float32)
     hat, ne = topk_ef(x, e, k=k, block=block)
@@ -133,6 +144,10 @@ def test_topk_ef_keeps_exactly_k_on_ties():
     for b in range(hat.shape[0]):
         kept = np.flatnonzero(hat[b])
         assert kept.tolist() == list(range(k)), (b, kept)
+    vals, idx, _ = topk_ef_sparse(x, e, k=k, block=block)
+    starts = np.arange(n // block)[:, None] * block
+    assert np.array_equal(np.asarray(idx), starts + np.arange(k))
+    assert (np.asarray(vals) == 1.0).all()
     # mixed signs and partial ties
     x2 = jnp.asarray(np.tile([2.0, -2.0, 1.0, -1.0], block // 4), jnp.float32)
     h2, _ = topk_ef(x2, jnp.zeros(block, jnp.float32), k=3, block=block)
@@ -141,13 +156,18 @@ def test_topk_ef_keeps_exactly_k_on_ties():
     # matches the dense blocktopk compressor bit for bit on ties
     comp = make_compressor("blocktopk", k / block, block)
     assert np.array_equal(np.asarray(comp.compress(x)), np.asarray(hat.reshape(-1)))
+    sel = comp.select(x)
+    assert np.array_equal(np.asarray(sel.idx), np.asarray(idx).reshape(-1))
+    assert np.array_equal(np.asarray(sel.vals), np.asarray(vals).reshape(-1))
 
 
 @pytest.mark.parametrize("n,block,k", [(256, 64, 4), (4096, 2048, 32),
-                                       (8192, 1024, 1)])
+                                       (8192, 1024, 1), (_steps_n(128), 128, 3),
+                                       (_steps_n(256), 256, 4)])
 def test_topk_ef_sparse_matches_dense_kernel(n, block, k):
     """The compacted (vals, idx) output scatters back to exactly the dense
-    kernel's hat, the fused new_err is identical, and idx are global."""
+    kernel's hat, the fused new_err is identical, idx are global, and both
+    agree bit for bit with the jnp blocktopk compressor."""
     x, e = _pair(7, n)
     hat, ne = topk_ef(x, e, k=k, block=block)
     vals, idx, ne2 = topk_ef_sparse(x, e, k=k, block=block)
@@ -155,22 +175,29 @@ def test_topk_ef_sparse_matches_dense_kernel(n, block, k):
     rec = jnp.zeros(n, jnp.float32).at[idx.reshape(-1)].set(vals.reshape(-1))
     assert np.array_equal(np.asarray(rec), np.asarray(hat))
     assert np.array_equal(np.asarray(ne2), np.asarray(ne))
+    comp = make_compressor("blocktopk", k / block, block)
+    sel = comp.select(x + e)
+    assert np.array_equal(np.asarray(sel.idx), np.asarray(idx).reshape(-1))
+    assert np.array_equal(np.asarray(sel.vals), np.asarray(vals).reshape(-1))
+    assert np.array_equal(np.asarray(comp.compress(x + e)), np.asarray(hat))
     # per-block indices live in that block's global range
     lo = np.arange(n // block)[:, None] * block
     assert ((np.asarray(idx) >= lo) & (np.asarray(idx) < lo + block)).all()
 
 
-def test_kernel_impl_topk_select_leaf_matches_compressor():
+@pytest.mark.parametrize("block,sizes", [(64, (64, 100, 300)),
+                                         (128, (_steps_n(128) - 50,))])
+def test_kernel_impl_topk_select_leaf_matches_compressor(block, sizes):
     """KernelImpl's fused selection agrees with the jnp compressor.select
-    (vals/idx bit-identical incl. padded tails) and its new_err with the
-    dense EF identity."""
-    ki = KernelImpl(block=64)
+    (vals/idx bit-identical incl. padded tails, over one grid step and
+    several) and its new_err with the dense EF identity."""
+    ki = KernelImpl(block=block)
     r = np.random.default_rng(9)
-    for n in (64, 100, 300):
+    for n in sizes:
         x = jnp.asarray(r.normal(size=n), jnp.float32)
         e = jnp.asarray(r.normal(size=n) * 0.2, jnp.float32)
         sel, ne = ki.topk_select_leaf(1 / 4, x, e)
-        comp = make_compressor("blocktopk", 1 / 4, 64)
+        comp = make_compressor("blocktopk", 1 / 4, block)
         ref_sel = comp.select(x + e)
         assert np.array_equal(np.asarray(sel.idx), np.asarray(ref_sel.idx)), n
         assert np.array_equal(np.asarray(sel.vals), np.asarray(ref_sel.vals))

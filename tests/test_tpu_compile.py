@@ -4,7 +4,9 @@ Compiled for a described ``v5e:2x2`` topology with the TPU's own compiler —
 no chip is attached, so nothing runs: each test proves that Mosaic accepts
 the kernel at xlstm-350m's largest leaf (the 50304 x 1024 embedding,
 51,511,296 elements) and at one layer's ``w_q`` slice (1024 x 2048), and
-that the compiled program holds the kernel (a ``tpu_custom_call``).
+that the compiled program holds the kernel (a ``tpu_custom_call``). The
+top-k kernels, whose tile height follows the block size, also compile at
+HuBERT-XLarge's widest leaf, so their tile is checked against VMEM.
 
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker that runs
@@ -23,6 +25,8 @@ from repro.kernels.sign_ef import sign_ef
 from repro.kernels.topk_ef import topk_ef, topk_ef_sparse
 
 SIZES = {"embed": 50304 * 1024, "layer_w_q": 1024 * 2048}
+#: plus the 21-layer stack of HuBERT-XLarge's 1280 x 5120 FFN weight
+TOPK_SIZES = {**SIZES, "hubert_ffn": 1280 * 5120 * 21}
 HP = dict(eta=0.5, beta1=0.9, beta2=0.99, eps=1e-3)
 
 
@@ -58,10 +62,10 @@ def _layout(n):
     return bs, nb, nb * bs
 
 
-@pytest.mark.parametrize("size", SIZES)
+@pytest.mark.parametrize("size", TOPK_SIZES)
 @pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
 def test_topk_ef_compiles(one_chip, size, sparse):
-    bs, nb, n = _layout(SIZES[size])
+    bs, nb, n = _layout(TOPK_SIZES[size])
     kernel = topk_ef_sparse if sparse else topk_ef
     x = _arg(one_chip, (n,))
     _compiles_with_kernel(
